@@ -19,9 +19,11 @@ Two trims, both deliberate:
   absolute step times compared across configurations.
 
 ``run_rows_subprocess`` is the other shared pattern: multi-device
-benches fork a child with ``--xla_force_host_platform_device_count``
-(the parent keeps the real 1-device CPU backend) and the child reports
-``ROW,name,us,derived`` lines that the parent forwards to ``emit``.
+benches fork a child on forced-host CPU devices
+(``--xla_force_host_platform_device_count``; the parent keeps the real
+1-device CPU backend) and the child reports ``ROW,name,us,derived``
+lines that the parent forwards to ``emit``; a failed child fails the
+run.
 
 Timed cells also emit ``bench.<name>`` spans through the §14 tracer
 (no-ops unless a bench activated one), and every BENCH_*.json row
@@ -106,13 +108,18 @@ def interleaved_trimmed(calls: Dict[str, Callable[[], object]],
 def run_rows_subprocess(script: str, emit: Callable[[str, float, str], None],
                         *, errname: str, devices: int = 4,
                         timeout: int = 900) -> None:
-    """Run ``script`` in a child python with ``devices`` forced host
+    """Run ``script`` in a child python on ``devices`` forced-host CPU
     devices and forward its ``ROW,name,us,derived`` stdout lines to
-    ``emit``. Failures become a single ``{errname}.error`` row instead
-    of killing the whole bench run. The child's PYTHONPATH gets both
-    ``src`` and the repo root (so scripts can import this module)."""
+    ``emit``. For CPU devices only: the child runs with
+    ``JAX_PLATFORMS=cpu``, because on a chip host the parent, which has
+    touched JAX, holds the chip and a child that needs it fails or hangs.
+    A child that fails or times out raises ``RuntimeError`` naming
+    ``errname``, so the bench run exits non-zero. The child's PYTHONPATH
+    gets both ``src`` and the repo root (so scripts can import this
+    module)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={devices}").strip()
@@ -122,13 +129,12 @@ def run_rows_subprocess(script: str, emit: Callable[[str, float, str], None],
         proc = subprocess.run([sys.executable, "-c", script], env=env,
                               capture_output=True, text=True,
                               timeout=timeout)
-    except subprocess.TimeoutExpired:
-        emit(f"{errname}.error", 0.0, f"subprocess_timeout:{timeout}s")
-        return
+    except subprocess.TimeoutExpired as e:
+        raise RuntimeError(f"{errname} bench child timed out after "
+                           f"{timeout}s") from e
     if proc.returncode != 0:
-        emit(f"{errname}.error", 0.0,
-             f"subprocess_failed:{proc.stderr.strip()[-200:]}")
-        return
+        raise RuntimeError(f"{errname} bench child failed "
+                           f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}")
     for line in proc.stdout.splitlines():
         if line.startswith("ROW,"):
             _, name, us, derived = line.split(",", 3)

@@ -55,6 +55,7 @@ def main():
                          "spans to PATH")
     cli.add_serve_args(ap)
     args = ap.parse_args()
+    cli.use_compile_cache()
 
     if args.ckpt:
         sess = InferenceSession.restore(

@@ -276,8 +276,6 @@ import json
 import sys
 
 rows = {r["name"]: r for r in json.load(open(sys.argv[1]))["rows"]}
-if "grad_comm.error" in rows:
-    sys.exit(f"grad_comm bench failed: {rows['grad_comm.error']['derived']}")
 mono = rows["grad_comm.micro.monolithic"]["us_per_call"]
 ov = rows["grad_comm.micro.overlap"]["us_per_call"]
 # regression gate: the overlapped lowering must not lose >10% to the

@@ -22,7 +22,7 @@ and elastic re-planning when the device count shrinks.
 
 Serving (DESIGN.md §15): ``compile(RunConfig(mode="infer"))`` returns a
 forward-only ``repro.serve.InferenceSession`` instead — no optimizer
-state, donated inputs, restorable straight from training checkpoints —
+state, restorable straight from training checkpoints —
 whose ``.serve()`` starts the batched request harness.
 """
 from repro.api import supervisor

@@ -8,8 +8,31 @@ driver-side and still left each example threading six kwargs.)
 from __future__ import annotations
 
 import dataclasses
+import os
 
 from repro.api.config import RunConfig
+
+# the checkout's root: src/repro/api/cli.py -> three levels up
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a driver process
+    and return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set,
+    is left to JAX, which reads it itself; otherwise the cache lives at
+    the fixed path ``<checkout>/.jax_cache`` (the path is part of the
+    cache key, so it must not move between runs). Call it from a
+    driver's setup, before the first compile — never from a library
+    module or a test."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def add_session_args(ap) -> None:
@@ -87,7 +110,10 @@ def harness_kwargs(args) -> dict:
 
 
 def config_from_args(base: RunConfig, args) -> RunConfig:
-    """Apply parsed ``add_session_args`` flags over a preset config."""
+    """Apply parsed ``add_session_args`` flags over a preset config.
+    This is the training drivers' setup step, so it also turns on the
+    persistent compilation cache (``use_compile_cache``)."""
+    use_compile_cache()
     over = {"data": args.data, "spatial": args.model,
             "pipeline": args.pipeline, "micro_batches": args.micro_batches,
             "pipeline_schedule": args.pipeline_schedule}

@@ -80,7 +80,7 @@ class RunConfig:
     smoke: bool = False
     # --- mode (DESIGN.md §15): "train" compiles the full training
     # Session; "infer" compiles a forward-only InferenceSession (no
-    # optimizer state, inference precision policy, donated inputs).
+    # optimizer state, inference precision policy).
     mode: str = "train"
     global_batch: int = 4
     data: int = 1
@@ -398,13 +398,16 @@ class RunConfig:
                     "checkpoint_dir",
                     "set checkpoint_dir=, or drop keep_last")
 
+        import jax
+
         if device_count is None:
-            import jax
             device_count = jax.device_count()
         if self.data * self.spatial > device_count:
-            hint = ("reduce the degrees, or force host devices with "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count="
-                    f"{self.data * self.spatial}")
+            hint = "reduce the degrees, or run on more devices"
+            if jax.default_backend() == "cpu":
+                hint = ("reduce the degrees, or force host devices with "
+                        "XLA_FLAGS=--xla_force_host_platform_device_count="
+                        f"{self.data * self.spatial}")
             if self.mode == "infer":
                 hint = self._spatial_fix(cfg, device_count, hint)
             raise RunConfigError(

@@ -27,7 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.api.config import RunConfig, RunConfigError
 from repro.configs.base import ConvNetConfig
@@ -231,12 +231,27 @@ def _resolve_plan(config: RunConfig, cfg: ConvNetConfig,
     return plan, explicit or "fp32"
 
 
+def _commit_state(params, opt_state, mesh, plan, grad_comm):
+    """Place fresh state where the step's shard_map leaves its outputs:
+    params replicated, a ZeRO-1 (``reduce_scatter``) state's flat
+    buckets sharded over the entry stage's batch axes, scalars
+    replicated. Uncommitted arrays would give the first step a program
+    that no later step reuses, so the second step would compile again."""
+    rep = NamedSharding(mesh, P())
+    flat = NamedSharding(mesh, P(tuple(plan.stages[0].batch_axes)))
+
+    def place(leaf):
+        sharded = grad_comm == "reduce_scatter" and leaf.ndim
+        return jax.device_put(leaf, flat if sharded else rep)
+
+    return jax.device_put(params, rep), jax.tree.map(place, opt_state)
+
+
 def compile(config: RunConfig):  # noqa: A001 - the API verb
     """Validate ``config``, resolve plan/precision/grad-comm, build the
     mesh and optimizer state, and return a live ``Session`` — or, for
     ``mode="infer"``, a forward-only ``InferenceSession`` (DESIGN.md
-    §15: no optimizer state, donated inputs, same plan-sharded
-    forward)."""
+    §15: no optimizer state, the same plan-sharded forward)."""
     if config.mode == "infer":
         # deferred: repro.serve.session imports this module
         from repro.serve.session import compile_infer
@@ -276,6 +291,9 @@ def _compile(config: RunConfig, *, abstract_state: bool) -> "Session":
 
     params, opt_state = (jax.eval_shape(build_state) if abstract_state
                          else build_state())
+    if not (abstract_state or pipelined):
+        params, opt_state = _commit_state(params, opt_state, mesh, plan,
+                                          grad_comm)
     if pipelined:
         step_fn = train_step_lib.make_pipeline_train_step(
             cfg, meshes, optimizer, plan=plan,
@@ -318,7 +336,7 @@ class Session:
         # accumulator (no per-step host sync), resumes set by the
         # supervisor / restore path
         self._guarded_steps = 0
-        self._applied_acc = jnp.zeros((), jnp.float32)
+        self._applied_acc = None  # set at the first guarded step
         self.resumes = 0
         # §14 observability: every Session owns a Tracer + registry; the
         # tracer only becomes the process-active one (and thus receives
@@ -370,6 +388,9 @@ class Session:
                 self.params, self.opt_state, loss, applied = self._step_fn(
                     self.params, self.opt_state, x, y, seed)
                 self._guarded_steps += 1
+                if self._applied_acc is None:
+                    # laid out like `applied`, so the add compiles once
+                    self._applied_acc = jnp.zeros_like(applied)
                 self._applied_acc = self._applied_acc + applied
             else:
                 self.params, self.opt_state, loss = self._step_fn(
@@ -423,6 +444,13 @@ class Session:
         return fn(params, x, y)
 
     # --------------------------------------------------- introspection ----
+    def _skipped(self) -> float:
+        """Guarded steps whose update was vetoed (syncs the lazy
+        accumulator)."""
+        if self._applied_acc is None:
+            return 0.0
+        return self._guarded_steps - float(self._applied_acc)
+
     def telemetry(self) -> Dict[str, float]:
         """§11 guard/recovery counters: ``skipped_steps`` (guarded steps
         whose update was vetoed), ``loss_scale`` (the live fp16 scale, 1
@@ -443,8 +471,7 @@ class Session:
         ``MetricsRegistry`` gauges and the returned dict is read back
         out of the registry — same keys, same values, one metrics
         surface (``session._metrics``) for every other consumer."""
-        skipped = (self._guarded_steps - float(self._applied_acc)
-                   if self._guarded_steps else 0.0)
+        skipped = self._skipped()
         scale = (float(self.opt_state.loss_scale)
                  if isinstance(self.opt_state, precision_lib.MPState)
                  else 1.0)

@@ -290,11 +290,9 @@ def run(config: RunConfig, steps: int, *,
                 sess = _start_session(cfg_now, root, report, verbose)
                 if loader_mode:
                     batch_fn = _loader_batch_fn(sess, cfg_now)
-                prev_skipped = (sess._guarded_steps
-                                - float(sess._applied_acc))
-                # the first two steps pay jit compiles (the second traces
-                # again once params carry committed shardings): no watchdog
-                warming = 2
+                prev_skipped = sess._skipped()
+                # the first step pays the jit compile: no watchdog
+                warming = 1
             while sess.step_count < steps:
                 t = sess.step_count
                 t0 = time.perf_counter()
@@ -311,8 +309,7 @@ def run(config: RunConfig, steps: int, *,
                     report.recovery_s.append(time.perf_counter()
                                              - pending[0])
                     pending = None
-                skipped = (sess._guarded_steps - float(sess._applied_acc)
-                           if config.resolved_guard else 0.0)
+                skipped = sess._skipped() if config.resolved_guard else 0.0
                 consec_bad = (consec_bad + 1
                               if skipped > prev_skipped
                               or not math.isfinite(loss) else 0)

@@ -1,12 +1,8 @@
-"""JAX version-compatibility shims.
+"""Thin wrappers over the jax surface the repo uses (jax 0.9).
 
-The repo is written against the modern surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``), but the oldest
-supported runtime is jax 0.4.3x, where ``shard_map`` lives in
-``jax.experimental.shard_map`` (with ``check_rep`` instead of
-``check_vma``) and meshes take no ``axis_types``. Every internal call site
-routes through these two helpers so the rest of the codebase never
-branches on the jax version.
+Each helper pins the one argument convention the codebase relies on, so
+call sites stay short: ``shard_map`` with the replication check off, a
+``dict`` from ``cost_analysis``, and meshes with explicit ``Auto`` axes.
 """
 from __future__ import annotations
 
@@ -16,57 +12,31 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions (replication checks off).
-
-    ``check=False`` maps to ``check_vma=False`` on modern jax and
-    ``check_rep=False`` on 0.4.x — the conv-net train steps mix manually
-    replicated params with sharded activations, which the static checker
-    rejects either way.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
+    """``jax.shard_map`` with ``check_vma=check`` (off by default): the
+    conv-net train steps mix manually replicated params with sharded
+    activations, which the static checker rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across versions: 0.4.x returns a
-    one-element list of dicts (per partition), newer jax a plain dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict (empty when the backend
+    reports none)."""
+    return compiled.cost_analysis() or {}
 
 
 def set_mesh(mesh):
-    """``jax.set_mesh`` context across versions: 0.4.x ``Mesh`` objects are
-    themselves the resource-env context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.set_mesh`` context."""
+    return jax.set_mesh(mesh)
 
 
 def axis_size(axis_name) -> int:
-    """``lax.axis_size`` across jax versions.
-
-    On 0.4.x there is no ``lax.axis_size``; ``lax.psum(1, name)`` of a
-    literal is constant-folded to the axis size at trace time, so it is a
-    static int in both cases (no collective is emitted).
-    """
-    import jax.lax as lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """``lax.axis_size``: the static size of a named mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with explicit (Auto) axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with explicit ``Auto`` axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))

@@ -23,7 +23,7 @@ Two halves:
 
 * **Measurement** — ``trace_peak_bytes`` replays the *actual traced
   program*: it runs a last-use liveness scan over the jaxpr of the real
-  forward+backward (inlining ``pjit``/``remat2``/``shard_map`` bodies;
+  forward+backward (inlining ``jit``/``remat2``/``shard_map`` bodies;
   shard_map bodies carry per-device local shapes, so the result is peak
   bytes per device), taking the max over program points of live buffer
   bytes. It knows nothing of the analytic model — what jax saved for
@@ -386,9 +386,8 @@ def data_parallel_peak_bytes(
 
 # --------------------------------------------- traced-program liveness ----
 _SUBJAXPR_PRIMS = {
-    "pjit", "remat2", "remat", "closed_call", "core_call", "xla_call",
-    "custom_jvp_call", "custom_jvp_call_jaxpr",
-    "custom_vjp_call", "custom_vjp_call_jaxpr", "shard_map",
+    "jit", "remat2", "closed_call", "custom_jvp_call", "custom_vjp_call",
+    "shard_map",
 }
 
 
@@ -465,7 +464,7 @@ def _jaxpr_peak(jaxpr) -> int:
 
 
 def _find_shard_map(jaxpr, depth: int = 0):
-    """First shard_map body reachable through pjit wrappers (its shapes
+    """First shard_map body reachable through jit wrappers (its shapes
     are per-device local)."""
     if depth > 4:
         return None
@@ -476,7 +475,7 @@ def _find_shard_map(jaxpr, depth: int = 0):
                     return v
                 if type(v).__name__ == "ClosedJaxpr":
                     return v.jaxpr
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             sub = _find_shard_map(eqn.params["jaxpr"].jaxpr, depth + 1)
             if sub is not None:
                 return sub

@@ -3,13 +3,14 @@
 ``compile_infer(RunConfig(mode="infer")) -> InferenceSession`` is the
 serving counterpart of ``repro.api.compile``: the same validate ->
 plan -> mesh assembly path, but the program it builds is the
-plan-sharded FORWARD only — no optimizer state, no gradient reduction,
-inputs donated (where the backend supports it) because nothing outlives
-the call. The forward reuses the §3 overlapped-halo conv and §5
-in-graph resharding, which is the paper's capacity argument applied to
-serving: a volume too large for one device's memory is served across
-the spatial group, and ``core.memory.infer_peak_bytes`` prices the
-per-device peak falling with the spatial degree.
+plan-sharded FORWARD only — no optimizer state, no gradient reduction.
+The request buffer is not donated: no output of either model has the
+volume's shape, so XLA could never reuse it, and donation would only
+delete the caller's array. The forward reuses the §3 overlapped-halo
+conv and §5 in-graph resharding, which is the paper's capacity argument
+applied to serving: a volume too large for one device's memory is
+served across the spatial group, and ``core.memory.infer_peak_bytes``
+prices the per-device peak falling with the spatial degree.
 
 Checkpoints written by training ``Session.save`` restore directly:
 ``InferenceSession.restore(path)`` reads the embedded run config,
@@ -68,13 +69,11 @@ class InferReport:
     precision: str
     param_count: int
     modeled_peak: "memory_lib.MemoryBreakdown"
-    donate: bool
 
     def __str__(self) -> str:
         return (
             f"InferenceSession[{self.plan_name}]\n"
-            f"  mesh {self.mesh_shape}  precision={self.precision}  "
-            f"donate={self.donate}\n"
+            f"  mesh {self.mesh_shape}  precision={self.precision}\n"
             f"  params {self.param_count / 1e6:.2f}M  "
             f"modeled forward peak/device {self.modeled_peak.describe()}")
 
@@ -134,9 +133,6 @@ class InferenceSession:
         self.plan: plan_lib.ParallelPlan = plan
         self.precision: str = precision_lib.get(precision).name
         self.params = params
-        # donation lets XLA reuse the request buffer as workspace; the
-        # CPU backend can't, and each donated call would warn
-        self.donate: bool = jax.default_backend() != "cpu"
         self._fwd_fns: Dict[int, Any] = {}
         self._eval_fns: Dict[int, Any] = {}
         self._harnesses: list = []
@@ -174,7 +170,7 @@ class InferenceSession:
                 self.cfg, self.mesh, plan=self.plan,
                 use_pallas=self.config.use_pallas,
                 overlap=self.config.overlap_halo,
-                precision=self.precision, donate=self.donate)
+                precision=self.precision)
             self._fwd_fns[batch] = fn
         return fn
 
@@ -182,8 +178,7 @@ class InferenceSession:
         """Forward a batch of volumes: CosmoFlow returns ``(B, out_dim)``
         predictions, the U-Net per-voxel logits in the plan's level-0
         layout. ``x.shape[0]`` must be a multiple of the plan's data
-        degree. On backends with donation the input buffer is consumed —
-        pass a fresh array (numpy inputs are always safe)."""
+        degree."""
         if self._closed:
             raise RuntimeError("InferenceSession is closed")
         x = jnp.asarray(x)
@@ -266,8 +261,7 @@ class InferenceSession:
         return InferReport(
             plan_name=self.plan.name, mesh_shape=dict(self.mesh.shape),
             precision=self.precision,
-            param_count=self.cfg.param_count(), modeled_peak=peak,
-            donate=self.donate)
+            param_count=self.cfg.param_count(), modeled_peak=peak)
 
     # ------------------------------------------------------ checkpoint ----
     @classmethod

@@ -490,15 +490,13 @@ def make_convnet_forward_step(
     overlap: Optional[bool] = None,
     plan: Optional["plan_lib.ParallelPlan"] = None,
     precision=None,
-    donate: bool = True,
 ):
     """Returns fwd(params, x) -> preds: the serving forward (§15).
 
     The same plan-sharded forward the eval step runs — overlapped-halo
     conv (§3) and in-graph resharding (§5) included — but with no loss
-    term and, by default, the input batch donated: an inference step
-    keeps no activations alive past the call, so XLA may reuse the
-    request buffer as workspace. CosmoFlow returns (B, out_dim)
+    term. The input is not donated: no output has its shape, so XLA
+    could not reuse its buffer. CosmoFlow returns (B, out_dim)
     predictions (sharded over the FC stage's batch axes); the U-Net
     returns per-voxel logits in the plan's level-0 layout."""
     plan = resolve_convnet_plan(cfg, mesh, spatial_axes=spatial_axes,
@@ -525,7 +523,7 @@ def make_convnet_forward_step(
                 else P(dspec, *spatial_axes, None))
     fn = compat.shard_map(local_fwd, mesh=mesh, in_specs=(P(), x_spec),
                           out_specs=out_spec)
-    return jax.jit(fn, donate_argnums=(1,) if donate else ())
+    return jax.jit(fn)
 
 
 # ------------------------------------------------- pipeline groups (§13) --

@@ -243,10 +243,14 @@ def maxdiff(a, b):
                                    np.asarray(b[k], np.float32))))
                for k in a)
 
-# M=1: one micro-batch IS the batch (BN included) -> fp-tolerance parity
+# M=1: one micro-batch IS the batch (BN included) -> fp-tolerance parity.
+# The BN statistics are psum'd in another grouping, and Adam's first
+# steps move each param by about +-lr whatever its gradient's size, so a
+# near-zero gradient element whose fp32 rounding flips its sign drifts
+# apart by up to 2*lr per step: 6e-3 over the 3 steps at lr 1e-3.
 p1, l1 = run_pipe(1, '1f1b')
-assert abs(l1 - float(l_ref)) <= 1e-5, (l1, float(l_ref))
-assert maxdiff(p1, p_ref) <= 1e-4
+assert abs(l1 - float(l_ref)) <= 1e-4, (l1, float(l_ref))
+assert maxdiff(p1, p_ref) <= 2 * 1e-3 * 3, maxdiff(p1, p_ref)
 
 # M=4: 1f1b vs the sequential oracle is BITWISE (same jits, same order)
 p2, l2 = run_pipe(4, '1f1b')
@@ -331,7 +335,6 @@ params = jax.tree.map(
     jax.eval_shape(lambda k: cosmoflow.init_params(k, cfg),
                    jax.random.PRNGKey(0)))
 gparams = pipeline_group_params(cfg, plan, params)[0]
-bplan = grad_comm.make_plan(gparams)
 
 mesh = compat.make_mesh((2,), ('data',))
 h = jnp.zeros((2, W, W, W, cfg.in_channels))
@@ -362,7 +365,13 @@ def find_jaxpr_with(jaxpr, prim):
                         return r
     return None
 
-body = find_jaxpr_with(jax.make_jaxpr(f)(gparams, h).jaxpr, 'psum')
+# one hook per leaf: under the default policy both smoke conv kernels
+# coalesce into one flat bucket, whose single psum can only follow the
+# group's last weight cotangent, so no reduction could precede compute
+with grad_comm.bucket_policy(small_thresh_elems=1):
+    bplan = grad_comm.make_plan(gparams)
+    body = find_jaxpr_with(jax.make_jaxpr(f)(gparams, h).jaxpr, 'psum')
+assert bplan.num_buckets == 2, bplan.num_buckets
 names = [e.primitive.name for e in body.eqns]
 n_psum = names.count('psum')
 # per-micro backward reduces through the SAME bucket hooks as the

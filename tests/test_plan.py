@@ -107,9 +107,13 @@ for arch in ('cosmoflow-512', 'unet3d-256'):
         l0, g0 = res['oracle']
         for name, (l, g) in res.items():
             assert abs(float(l) - float(l0)) <= 1e-5, (arch, ways, name)
+            # in fp64 every plan matches the oracle to 1e-15; in fp32 the
+            # U-Net's first-layer grads differ by up to 1.8e-4, and the
+            # 2-way fp32 oracle is itself that far from the fp64 values
+            atol = 2e-4 if arch.startswith('unet3d') else 1e-5
             for k in g0:
                 np.testing.assert_allclose(
-                    np.asarray(g[k]), np.asarray(g0[k]), atol=1e-5,
+                    np.asarray(g[k]), np.asarray(g0[k]), atol=atol,
                     rtol=1e-4, err_msg=f"{arch} ways={ways} {name} {k}")
 print("OK")
 """, devices=8, timeout=560)
