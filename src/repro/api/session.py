@@ -631,7 +631,7 @@ class Session:
             if not all(f"probe.{p}" in have for p in probes):
                 self.profile(batch, reps=reps)
             have = self.tracer.span_seconds()
-            if "io.load" not in have and "io.load.sync" not in have:
+            if "io.load" not in have:
                 self._drive_io_sample()
         finally:
             if prev is not None and prev is not self.tracer:

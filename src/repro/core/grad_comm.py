@@ -167,8 +167,11 @@ def _psum_hook(axes: Tuple[str, ...]):
     def ident(x):
         return x
 
-    ident.defvjp(lambda x: (x, None),
-                 lambda _, g: (lax.psum(g, axes),))
+    @jax.named_scope("grad_comm")
+    def bwd(_, g):
+        return (lax.psum(g, axes),)
+
+    ident.defvjp(lambda x: (x, None), bwd)
     return ident
 
 
@@ -184,6 +187,7 @@ def _bucket_psum_hook(axes: Tuple[str, ...], n: int):
     def ident(*xs):
         return tuple(xs)
 
+    @jax.named_scope("grad_comm")
     def bwd(_, gs):
         flat = lax.psum(jnp.concatenate([g.reshape(-1) for g in gs]), axes)
         out, off = [], 0
@@ -302,6 +306,7 @@ def _pad_to(flat: jax.Array, padded: int) -> jax.Array:
     return flat
 
 
+@jax.named_scope("grad_comm")
 def reduce_scatter_grads(grads, plan: Plan, data_axes: Sequence[str]):
     """Bucket-flatten local grads; ``psum_scatter`` each bucket over the
     data axes so shard i holds the fully reduced chunk i. Returns a tuple
@@ -333,6 +338,7 @@ def param_shards(params, plan: Plan, data_axes: Sequence[str]):
     return tuple(out)
 
 
+@jax.named_scope("grad_comm")
 def all_gather_params(shards, plan: Plan, data_axes: Sequence[str],
                      template):
     """Inverse of the scatter: gather updated shards over the data axes,

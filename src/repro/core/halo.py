@@ -44,6 +44,14 @@ from repro.core import compat
 from repro.obs import trace as trace_lib
 
 
+def _send(x: jax.Array, axis_name: str, perm) -> jax.Array:
+    """One halo ``ppermute``, under the ``halo`` scope that names it in a
+    device trace (one-shard boundary zeros are the conv's padding, not an
+    exchange, and stay outside it)."""
+    with jax.named_scope("halo"):
+        return lax.ppermute(x, axis_name, perm)
+
+
 def _shift_perm(n: int, direction: int):
     """Pairs (src, dst) shifting data by ``direction`` (+1: to next rank)."""
     if direction > 0:
@@ -81,7 +89,7 @@ def halo_exchange(
             perm = _shift_perm(n, +1)
             if wrap:
                 perm = perm + [(n - 1, 0)]
-            recv_lo = lax.ppermute(send, axis_name, perm)
+            recv_lo = _send(send, axis_name, perm)
         parts.append(recv_lo)
     parts.append(x)
     if hi > 0:
@@ -95,7 +103,7 @@ def halo_exchange(
             perm = _shift_perm(n, -1)
             if wrap:
                 perm = perm + [(0, n - 1)]
-            recv_hi = lax.ppermute(send, axis_name, perm)
+            recv_hi = _send(send, axis_name, perm)
         parts.append(recv_hi)
     return jnp.concatenate(parts, axis=dim)
 
@@ -176,7 +184,7 @@ def start_halo_exchange(
         parts = [p for p in (to_next, to_prev) if p is not None]
         packed = parts[0] if len(parts) == 1 else jnp.concatenate(parts, dim)
         trace_lib.count("halo.ppermutes")
-        recv = lax.ppermute(packed, axis_name, [(0, 1), (1, 0)])
+        recv = _send(packed, axis_name, [(0, 1), (1, 0)])
         # recv = [peer trailing lo rows | peer leading hi rows]
         recv_lo = lax.slice_in_dim(recv, 0, lo, axis=dim) if lo else None
         recv_hi = (lax.slice_in_dim(recv, recv.shape[dim] - hi,
@@ -198,13 +206,13 @@ def start_halo_exchange(
         if wrap:
             perm = perm + [(n - 1, 0)]
         trace_lib.count("halo.ppermutes")
-        recv_lo = lax.ppermute(to_next, axis_name, perm)
+        recv_lo = _send(to_next, axis_name, perm)
     if hi > 0:
         perm = _shift_perm(n, -1)
         if wrap:
             perm = perm + [(0, n - 1)]
         trace_lib.count("halo.ppermutes")
-        recv_hi = lax.ppermute(to_prev, axis_name, perm)
+        recv_hi = _send(to_prev, axis_name, perm)
     return HaloSlabs(recv_lo, recv_hi)
 
 
@@ -248,7 +256,7 @@ def exchange_carry_right(
     n = compat.axis_size(axis_name)
     if n == 1:
         return jnp.zeros_like(carry)
-    return lax.ppermute(carry, axis_name, _shift_perm(n, +1))
+    return _send(carry, axis_name, _shift_perm(n, +1))
 
 
 def all_gather_dim(x: jax.Array, axis_name: str, dim: int) -> jax.Array:

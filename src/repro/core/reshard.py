@@ -125,6 +125,7 @@ def shard_batch(y: jax.Array, axes: Sequence[str]) -> jax.Array:
     return y
 
 
+@jax.named_scope("reshard")
 def apply(
     h: jax.Array,
     src,

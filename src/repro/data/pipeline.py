@@ -139,7 +139,9 @@ class SpatialParallelLoader:
                 else:
                     self.stats.cache_bytes_redistributed += arr.nbytes
         else:
-            arr = self.store.read_hyperslab(sample, wide, what)
+            with trace_lib.span("io.read") as span:
+                arr = self.store.read_hyperslab(sample, wide, what)
+                span.set(bytes=arr.nbytes)
             with self._lock:
                 self.stats.pfs_bytes += arr.nbytes
                 if self.cache_enabled:
@@ -171,10 +173,40 @@ class SpatialParallelLoader:
             out[self._slab_key(idx, shape)] = self._rank_of[dev]
         return out
 
-    def _vector_labels(self, sample_ids: np.ndarray) -> jax.Array:
-        """Vector regression targets for a batch, cached as the placed
-        device array — ``store.target`` is only re-read (and the batch
-        only re-``device_put``) on a cache miss."""
+    def _read_shards(self, sample_ids: np.ndarray, shape, sharding,
+                     what: str) -> Tuple[Dict[Tuple, np.ndarray], int]:
+        """Read and stack each addressable device's shard of the batch,
+        keyed by ``_slab_key``, before anything is placed; and the bytes
+        the placement will move. One shard per device, in the order
+        ``make_array_from_callback`` asks for them (once when the
+        sharding is fully replicated)."""
+        ranks = self._rank_map(shape, sharding)
+        devices = sharding.addressable_devices_indices_map(tuple(shape))
+        indices = ([(slice(None),) * len(shape)]
+                   if sharding.is_fully_replicated else devices.values())
+        ready: Dict[Tuple, np.ndarray] = {}
+        for idx in indices:
+            # idx[0] selects samples; idx[1:4] is the spatial hyperslab.
+            key = self._slab_key(idx, shape)
+            slab = tuple(idx[1:]) if what == "y" else (
+                tuple(idx[1:-1]) + (slice(None),))
+            ready[key] = np.stack(
+                [self._fetch(int(s), slab, ranks[key], what)
+                 for s in sample_ids[idx[0]]], axis=0)
+        placed = sum(ready[self._slab_key(idx, shape)].nbytes
+                     for idx in devices.values())
+        return ready, placed
+
+    def _place(self, shape, sharding, ready) -> jax.Array:
+        """The host-to-device transfer of shards ``_read_shards`` made."""
+        return jax.make_array_from_callback(
+            shape, sharding, lambda idx: ready[self._slab_key(idx, shape)])
+
+    def _vector_targets(self, sample_ids: np.ndarray):
+        """The batch's vector regression targets: the placed array from
+        the label cache, else the host array read from the store, which
+        ``_place_targets`` places and caches — ``store.target`` is only
+        re-read (and the batch only re-``device_put``) on a miss."""
         key = tuple(int(s) for s in sample_ids)
         if self.cache_enabled:
             with self._lock:
@@ -184,49 +216,43 @@ class SpatialParallelLoader:
         tg = np.stack([self.store.target(int(s)) for s in sample_ids])
         with self._lock:
             self.stats.label_fetches += len(key)
+        return tg
+
+    def _place_targets(self, sample_ids: np.ndarray, tg) -> jax.Array:
+        if isinstance(tg, jax.Array):  # a label-cache hit
+            return tg
         y = jax.device_put(
             tg, NamedSharding(self.mesh, P(self.sharding.spec[0])))
         if self.cache_enabled:
             with self._lock:
-                self._label_cache[key] = y
+                self._label_cache[tuple(int(s) for s in sample_ids)] = y
         return y
 
     # ------------------------------------------------------------ batch ----
     def load_batch(self, sample_ids: np.ndarray):
-        """Build the sharded (N, D, H, W, C) global batch for these samples."""
-        with trace_lib.span("io.load.sync", samples=len(sample_ids)):
+        """Build the sharded (N, D, H, W, C) global batch for these
+        samples: the whole cost of one batch, on whichever thread runs
+        it (a prefetch worker, or the caller)."""
+        with trace_lib.span("io.load", samples=len(sample_ids)):
             return self._load_batch(sample_ids)
 
     def _load_batch(self, sample_ids: np.ndarray):
+        """Read every shard first (``io.read`` per store read), then
+        place them all inside one ``io.place`` span."""
         shape = (len(sample_ids),) + self.store.sample_shape
-        ranks = self._rank_map(shape, self.sharding)
-
-        def cb(idx: Tuple[slice, ...]) -> np.ndarray:
-            # idx[0] selects samples; idx[1:4] is the spatial hyperslab.
-            rank = ranks[self._slab_key(idx, shape)]
-            samples = sample_ids[idx[0]]
-            slab = tuple(idx[1:])
-            parts = [self._fetch(int(s), slab[:-1] + (slice(None),), rank)
-                     for s in samples]
-            return np.stack(parts, axis=0)
-
-        x = jax.make_array_from_callback(shape, self.sharding, cb)
-        if self.store.label_kind == "voxel" and self.label_sharding:
+        xs, nbytes = self._read_shards(sample_ids, shape, self.sharding, "x")
+        voxel = self.store.label_kind == "voxel" and self.label_sharding
+        if voxel:
             lshape = (len(sample_ids),) + self.store.sample_shape[:-1]
-            lranks = self._rank_map(lshape, self.label_sharding)
-
-            def cb_y(idx):
-                rank = lranks[self._slab_key(idx, lshape)]
-                samples = sample_ids[idx[0]]
-                slab = tuple(idx[1:])
-                parts = [self._fetch(int(s), slab, rank, what="y")
-                         for s in samples]
-                return np.stack(parts, axis=0)
-
-            y = jax.make_array_from_callback(lshape, self.label_sharding,
-                                             cb_y)
+            ys, ybytes = self._read_shards(sample_ids, lshape,
+                                           self.label_sharding, "y")
         else:
-            y = self._vector_labels(sample_ids)
+            tg = self._vector_targets(sample_ids)
+            ybytes = 0 if isinstance(tg, jax.Array) else tg.nbytes
+        with trace_lib.span("io.place", bytes=nbytes + ybytes):
+            x = self._place(shape, self.sharding, xs)
+            y = (self._place(lshape, self.label_sharding, ys) if voxel
+                 else self._place_targets(sample_ids, tg))
         return x, y
 
     def close(self) -> None:
@@ -239,7 +265,7 @@ class SampleParallelLoader(SpatialParallelLoader):
     rank and then scattered — per-rank I/O does not shrink with spatial
     parallelism. Used only by the I/O benchmark."""
 
-    def load_batch(self, sample_ids: np.ndarray):
+    def _load_batch(self, sample_ids: np.ndarray):
         full = []
         for s in sample_ids:
             key = (int(s), "x", "full")
@@ -250,7 +276,9 @@ class SampleParallelLoader(SpatialParallelLoader):
                 with self._lock:
                     self.stats.cache_bytes_local += arr.nbytes
             else:
-                arr = self.store.read_full(int(s))
+                with trace_lib.span("io.read") as span:
+                    arr = self.store.read_full(int(s))
+                    span.set(bytes=arr.nbytes)
                 with self._lock:
                     self.stats.pfs_bytes += arr.nbytes
                     if self.cache_enabled:
@@ -260,6 +288,9 @@ class SampleParallelLoader(SpatialParallelLoader):
         # the scatter to the spatial sharding = pure redistribution traffic
         with self._lock:
             self.stats.cache_bytes_redistributed += batch.nbytes
-        x = jax.device_put(batch, self.sharding)
-        y = self._vector_labels(sample_ids)
+        tg = self._vector_targets(sample_ids)
+        ybytes = 0 if isinstance(tg, jax.Array) else tg.nbytes
+        with trace_lib.span("io.place", bytes=batch.nbytes + ybytes):
+            x = jax.device_put(batch, self.sharding)
+            y = self._place_targets(sample_ids, tg)
         return x, y

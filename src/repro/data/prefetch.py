@@ -123,21 +123,16 @@ class PrefetchLoader:
         self._pos += gb
         return ids
 
-    def _traced_load(self, ids: np.ndarray):
-        # §14: the worker's whole read+place cost, on its own
-        # io-prefetch_* thread track — the measured side of the drift
-        # table's ``io`` row
-        with trace_lib.span("io.load", samples=len(ids)):
-            return self.inner.load_batch(ids)
-
     def _fill(self) -> None:
         while len(self._queue) < self.depth:
             ids = self._predict()
             if ids is None:
                 return
             key = tuple(int(i) for i in ids)
+            # the inner loader's ``io.load`` span lands on this
+            # worker's io-prefetch_* thread track (§14)
             self._queue.append(
-                (key, self._pool.submit(self._traced_load, ids)))
+                (key, self._pool.submit(self.inner.load_batch, ids)))
 
     @staticmethod
     def _discard(fut: Future) -> None:

@@ -161,55 +161,75 @@ def forward(
         ids = jnp.arange(h.shape[0])
     cur = plan.stage_for(0)
     for i in range(n):
-        st = plan.stage_for(i)
-        if st != cur:
-            h, ids = reshard.apply(h, cur, st, sample_ids=ids,
+        with jax.named_scope(f"block{i}"):
+            st = plan.stage_for(i)
+            if st != cur:
+                h, ids = reshard.apply(h, cur, st, sample_ids=ids,
+                                       oracle=reshard_oracle)
+                cur = st
+            # block 4 (0-indexed 3) is the strided conv
+            stride = 2 if i == 3 else 1
+            w = cst(marker.mark(params[f"conv{i}_w"]))
+            bn_params = ((cst(marker.mark(params[f"bn{i}_scale"])),
+                          cst(marker.mark(params[f"bn{i}_bias"])))
+                         if cfg.batchnorm else ())
+
+            def block(h, w, *bn, _part=cur.part, _stride=stride,
+                      _pool=i < npool):
+                with jax.named_scope("conv"):
+                    h = conv3d(h, w, _part, stride=_stride,
+                               use_pallas=use_pallas, overlap=overlap)
+                with jax.named_scope("norm"):
+                    if bn:
+                        # leaky-ReLU folded into the normalize pass (fused
+                        # Pallas kernel under use_pallas) — one HBM
+                        # round-trip, not two.
+                        h = dist_norm.distributed_batchnorm(
+                            h, bn[0], bn[1], bn_axes,
+                            use_pallas=use_pallas, activation_slope=0.01)
+                    else:
+                        h = jax.nn.leaky_relu(h, negative_slope=0.01)
+                if _pool:
+                    with jax.named_scope("pool"):
+                        h = maxpool3d(h, _part, window=2, stride=2,
+                                      overlap=overlap)
+                return h
+
+            if st.remat if plan_remat else flags.get("remat"):
+                block = jax.checkpoint(block)
+            h = block(h, w, *bn_params)
+    with jax.named_scope("head"):
+        # CNN -> FC stage boundary: the plan picks the batch repartition
+        # (all_to_all, no redundant compute) or the replicated gather
+        # (the legacy fallback — FC then runs redundantly on every
+        # spatial shard).
+        fc_stage = plan.stage_for(n)
+        if fc_stage != cur:
+            h, ids = reshard.apply(h, cur, fc_stage, sample_ids=ids,
                                    oracle=reshard_oracle)
-            cur = st
-        stride = 2 if i == 3 else 1  # block 4 (0-indexed 3) is the strided conv
-        w = cst(marker.mark(params[f"conv{i}_w"]))
-        bn_params = ((cst(marker.mark(params[f"bn{i}_scale"])),
-                      cst(marker.mark(params[f"bn{i}_bias"])))
-                     if cfg.batchnorm else ())
+        h = _fc_head(params, h, cfg, marker.mark, cst, train, dropout_rng,
+                     ids)
+    marker.assert_all_marked()
+    return h
 
-        def block(h, w, *bn, _part=cur.part, _stride=stride,
-                  _pool=i < npool):
-            h = conv3d(h, w, _part, stride=_stride, use_pallas=use_pallas,
-                       overlap=overlap)
-            if bn:
-                # leaky-ReLU folded into the normalize pass (fused Pallas
-                # kernel under use_pallas) — one HBM round-trip, not two.
-                h = dist_norm.distributed_batchnorm(
-                    h, bn[0], bn[1], bn_axes,
-                    use_pallas=use_pallas, activation_slope=0.01)
-            else:
-                h = jax.nn.leaky_relu(h, negative_slope=0.01)
-            if _pool:
-                h = maxpool3d(h, _part, window=2, stride=2, overlap=overlap)
-            return h
 
-        if st.remat if plan_remat else flags.get("remat"):
-            block = jax.checkpoint(block)
-        h = block(h, w, *bn_params)
-    # CNN -> FC stage boundary: the plan picks the batch repartition
-    # (all_to_all, no redundant compute) or the replicated gather (the
-    # legacy fallback — FC then runs redundantly on every spatial shard).
-    fc_stage = plan.stage_for(n)
-    if fc_stage != cur:
-        h, ids = reshard.apply(h, cur, fc_stage, sample_ids=ids,
-                               oracle=reshard_oracle)
+def _fc_head(params: Params, h: jax.Array, cfg: ConvNetConfig, mark, cst,
+             train: bool, dropout_rng: Optional[jax.Array],
+             ids: Optional[jax.Array]) -> jax.Array:
+    """Flatten, then FC layers with leaky-ReLU and dropout. Dropout masks
+    are per (sample, layer) and deterministic: identical across every
+    shard that computes a given sample (replicated FC heads agree;
+    repartitioned FC heads each own distinct samples) and invariant to
+    the mesh shape, the plan and a pipeline's split. ``ids`` are the
+    global ids of the local rows (``None``: their positions)."""
     h = h.reshape(h.shape[0], -1)
     n_fc = len(cfg.fc_dims) + 1
     for j in range(n_fc):
-        h = (h @ cst(marker.mark(params[f"fc{j}_w"]))
-             + cst(marker.mark(params[f"fc{j}_b"])))
+        h = (h @ cst(mark(params[f"fc{j}_w"]))
+             + cst(mark(params[f"fc{j}_b"])))
         if j < n_fc - 1:
             h = jax.nn.leaky_relu(h, negative_slope=0.01)
             if train and dropout_rng is not None:
-                # per-(sample, layer) deterministic masks: identical across
-                # every shard that computes a given sample (replicated FC
-                # heads agree; repartitioned FC heads each own distinct
-                # samples) and invariant to the mesh shape and the plan.
                 keep = 0.8
                 layer_rng = jax.random.fold_in(dropout_rng, j)
 
@@ -218,11 +238,9 @@ def forward(
                         jax.random.fold_in(layer_rng, sid), keep,
                         (h.shape[1],))
 
-                row_ids = (ids if ids is not None
-                           else jnp.arange(h.shape[0]))
+                row_ids = ids if ids is not None else jnp.arange(h.shape[0])
                 mask = jax.vmap(mask_row)(row_ids)
                 h = jnp.where(mask, h / keep, 0.0)
-    marker.assert_all_marked()
     return h
 
 
@@ -276,39 +294,26 @@ def forward_range(
         h = h.astype(policy.compute_dtype)
     part = SpatialPartitioning()  # group-local: no spatial axes
     for i in range(start, min(stop, n)):
-        stride = 2 if i == 3 else 1
-        w = cst(marker.mark(params[f"conv{i}_w"]))
-        h = conv3d(h, w, part, stride=stride)
-        if cfg.batchnorm:
-            h = dist_norm.distributed_batchnorm(
-                h, cst(marker.mark(params[f"bn{i}_scale"])),
-                cst(marker.mark(params[f"bn{i}_bias"])), bn_axes,
-                activation_slope=0.01)
-        else:
-            h = jax.nn.leaky_relu(h, negative_slope=0.01)
-        if i < npool:
-            h = maxpool3d(h, part, window=2, stride=2)
+        with jax.named_scope(f"block{i}"):
+            stride = 2 if i == 3 else 1
+            w = cst(marker.mark(params[f"conv{i}_w"]))
+            with jax.named_scope("conv"):
+                h = conv3d(h, w, part, stride=stride)
+            with jax.named_scope("norm"):
+                if cfg.batchnorm:
+                    h = dist_norm.distributed_batchnorm(
+                        h, cst(marker.mark(params[f"bn{i}_scale"])),
+                        cst(marker.mark(params[f"bn{i}_bias"])), bn_axes,
+                        activation_slope=0.01)
+                else:
+                    h = jax.nn.leaky_relu(h, negative_slope=0.01)
+            if i < npool:
+                with jax.named_scope("pool"):
+                    h = maxpool3d(h, part, window=2, stride=2)
     if stop > n:
-        h = h.reshape(h.shape[0], -1)
-        n_fc = len(cfg.fc_dims) + 1
-        for j in range(n_fc):
-            h = (h @ cst(marker.mark(params[f"fc{j}_w"]))
-                 + cst(marker.mark(params[f"fc{j}_b"])))
-            if j < n_fc - 1:
-                h = jax.nn.leaky_relu(h, negative_slope=0.01)
-                if train and dropout_rng is not None:
-                    keep = 0.8
-                    layer_rng = jax.random.fold_in(dropout_rng, j)
-
-                    def mask_row(sid):
-                        return jax.random.bernoulli(
-                            jax.random.fold_in(layer_rng, sid), keep,
-                            (h.shape[1],))
-
-                    row_ids = (sample_ids if sample_ids is not None
-                               else jnp.arange(h.shape[0]))
-                    mask = jax.vmap(mask_row)(row_ids)
-                    h = jnp.where(mask, h / keep, 0.0)
+        with jax.named_scope("head"):
+            h = _fc_head(params, h, cfg, marker.mark, cst, train,
+                         dropout_rng, sample_ids)
     marker.assert_all_marked()
     return h
 
@@ -351,7 +356,8 @@ def mse_loss(
     """
     if plan is not None:
         redundancy = plan.loss_redundancy
-        y = reshard.shard_batch(y, plan.batch_extension_axes)
+        with jax.named_scope("loss"):
+            y = reshard.shard_batch(y, plan.batch_extension_axes)
     else:
         redundancy = spatial_size
     pred = forward(
@@ -361,6 +367,8 @@ def mse_loss(
         use_pallas=use_pallas, overlap=overlap, grad_axes=grad_axes,
         reshard_oracle=reshard_oracle, precision=precision,
     )
-    n_global = global_batch or x.shape[0]
-    per_sample = jnp.mean(jnp.square(pred.astype(jnp.float32) - y), axis=-1)
-    return jnp.sum(per_sample) / (n_global * redundancy)
+    with jax.named_scope("loss"):
+        n_global = global_batch or x.shape[0]
+        per_sample = jnp.mean(jnp.square(pred.astype(jnp.float32) - y),
+                              axis=-1)
+        return jnp.sum(per_sample) / (n_global * redundancy)

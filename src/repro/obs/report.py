@@ -21,7 +21,7 @@ Phase mapping (the probes are cumulative prefixes of the step):
   probe.grad_comm``.
 * ``io``   — prior: staging the global batch through host memory at
   ``hw.mem_bw`` (the model has no store term either); measured mean of
-  the loader worker's ``io.load`` span (store read + device place per
+  the loader's ``io.load`` span (store read + stack + device place per
   batch).
 * ``step`` — modeled ``total``; measured ``probe.step`` (pipelined
   sessions measure only this row — their phases interleave across
@@ -164,7 +164,7 @@ def measured_phases(tracer) -> Dict[str, float]:
     """Per-phase seconds from a tracer's span aggregates. The probes are
     cumulative (fwd ⊂ bwd ⊂ grad_comm ⊂ step), so successive
     differences attribute each phase; io comes from the loader's
-    ``io.load`` worker span (or the sync loader's ``io.load.sync``)."""
+    ``io.load`` span, one per batch on whichever thread loaded it."""
     s = tracer.span_seconds()
 
     def mean(name: str) -> float:
@@ -184,6 +184,4 @@ def measured_phases(tracer) -> Dict[str, float]:
                              - mean("probe.grad_comm"), 0.0)
     if "io.load" in s:
         out["io"] = mean("io.load")
-    elif "io.load.sync" in s:
-        out["io"] = mean("io.load.sync")
     return out

@@ -55,6 +55,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs: Any) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -79,6 +82,10 @@ class _Span:
         self._tracer._record(self._name, self._t0, t1 - self._t0,
                              self._attrs)
         return False
+
+    def set(self, **attrs: Any) -> None:
+        """Add attributes known only inside the span (a read's bytes)."""
+        self._attrs = dict(self._attrs or {}, **attrs)
 
 
 class Tracer:

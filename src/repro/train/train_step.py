@@ -269,10 +269,12 @@ def _build_convnet_step(
             scale = precision_lib.current_scale(opt_state, policy)
             loss, grads = jax.value_and_grad(
                 lambda p: loss_fn(p) * scale)(params)
-            loss = lax.psum(loss / scale, all_axes)
+            with jax.named_scope("loss"):
+                loss = lax.psum(loss / scale, all_axes)
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params)
-            loss = lax.psum(loss, all_axes)
+            with jax.named_scope("loss"):
+                loss = lax.psum(loss, all_axes)
         if stage == "bwd":
             # timing-only probe: collapse the (per-device partial) grads
             # into one psummed scalar — forces the full backward without
@@ -282,7 +284,8 @@ def _build_convnet_step(
             return loss, lax.psum(gsum, all_axes)
 
         if mode == "monolithic":
-            grads = jax.tree.map(lambda g: lax.psum(g, all_axes), grads)
+            with jax.named_scope("grad_comm"):
+                grads = jax.tree.map(lambda g: lax.psum(g, all_axes), grads)
         if stage == "grad_comm":
             if mode == "reduce_scatter":
                 # pure-comm probe: scatter + gather, no optimizer math
@@ -292,26 +295,31 @@ def _build_convnet_step(
                     shards, bucket_plan, data_axes, grads)
             return loss, grads
 
-        applied = None
-        if guard:
-            # §11: one agreed verdict BEFORE the update. fp16 hands the
-            # loss-veto to its own skip machine (poisoned grads) so the
-            # scale still backs off; fp32/bf16 select after the update.
-            applied = guard_lib.agreed_finite(loss, grads, all_axes)
-            if policy.uses_scaling:
-                grads = guard_lib.poison_unless(applied, grads)
-        if mode == "reduce_scatter":
-            new_params, new_opt = grad_comm_lib.sharded_update(
-                optimizer, grads, opt_state, params, bucket_plan, data_axes)
-        else:
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
-        if guard:
-            if not policy.uses_scaling:
-                new_params = guard_lib.tree_select(applied, new_params,
-                                                  params)
-                new_opt = guard_lib.tree_select(applied, new_opt, opt_state)
-            return (new_params, new_opt, loss,
-                    applied.astype(jnp.float32))
+        with jax.named_scope("optimizer"):
+            applied = None
+            if guard:
+                # §11: one agreed verdict BEFORE the update. fp16 hands
+                # the loss-veto to its own skip machine (poisoned grads)
+                # so the scale still backs off; fp32/bf16 select after
+                # the update.
+                applied = guard_lib.agreed_finite(loss, grads, all_axes)
+                if policy.uses_scaling:
+                    grads = guard_lib.poison_unless(applied, grads)
+            if mode == "reduce_scatter":
+                new_params, new_opt = grad_comm_lib.sharded_update(
+                    optimizer, grads, opt_state, params, bucket_plan,
+                    data_axes)
+            else:
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
+            if guard:
+                if not policy.uses_scaling:
+                    new_params = guard_lib.tree_select(applied, new_params,
+                                                      params)
+                    new_opt = guard_lib.tree_select(applied, new_opt,
+                                                   opt_state)
+                return (new_params, new_opt, loss,
+                        applied.astype(jnp.float32))
         return new_params, new_opt, loss
 
     dspec = data_axes if len(data_axes) > 1 else data_axes[0]
@@ -1018,7 +1026,8 @@ def make_pipeline_train_step(
     else:
         def upd(p, s, g_):
             return optimizer.update(g_, s, p)
-    upd_j = jax.jit(upd, donate_argnums=(0, 1) if donate else ())
+    upd_j = jax.jit(jax.named_scope("optimizer")(upd),
+                    donate_argnums=(0, 1) if donate else ())
 
     def step(params, opt_states, x, y, seed):
         with trace_lib.span("pipe.place", micro_batches=M):

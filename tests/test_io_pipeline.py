@@ -4,6 +4,7 @@ owner-rank cache accounting, halo margin reads, and the supervisor's
 loader-backed bitwise kill-and-resume."""
 import dataclasses
 import tempfile
+import threading
 
 import jax
 import numpy as np
@@ -314,3 +315,99 @@ def test_supervisor_loader_mode_kill_resume_bitwise(tmp_path):
                faults.FaultSpec("device.loss", at_steps=(4,), max_fires=1))
     assert kill.restarts == 1 and kill.resumes == 1
     assert kill.losses == ref.losses  # bitwise across kill-and-resume
+
+
+# ------------------------------------------------------- loader spans ----
+def _span_tree(events):
+    """Each ``io.load`` span with the spans of its thread inside it."""
+    loads = [e for e in events if e.name == "io.load"]
+    return [(e, [c for c in events if c is not e and c.thread == e.thread
+                 and e.ts_ns <= c.ts_ns
+                 and c.ts_ns + c.dur_ns <= e.ts_ns + e.dur_ns])
+            for e in loads]
+
+
+@pytest.mark.parametrize("mode", ["prefetch", "sync", "fallback"])
+def test_loader_spans_one_load_per_batch(tmp_path, mode):
+    """One ``io.load`` per batch, on the thread that loaded it: a prefetch
+    worker, or the caller (a sync loader, or a prefetcher's fallback on
+    unpredicted ids). Inside it, one ``io.read`` per store read, whose
+    bytes add up to the batch's, and one ``io.place``."""
+    from repro.obs import trace as trace_lib
+
+    root = _dataset(str(tmp_path))
+    ld = _loader(root, seed=2, cache=False, pf=0 if mode == "sync" else 2)
+    tracer = trace_lib.enable()
+    try:
+        order = ld.schedule_for_epoch(0)
+        # the fallback's second batch is not the next chunk of the order
+        second = order[2:6] if mode == "fallback" else order[4:8]
+        out = [ld.load_batch(b) for b in (order[:4], second)]
+        ld.close()
+    finally:
+        trace_lib.disable(tracer)
+    events = [e for e in tracer.events() if e.dur_ns is not None]
+    assert not [e for e in events if e.name == "io.load.sync"]
+    tree = _span_tree(events)
+    caller = threading.current_thread().name
+    threads = [e.thread for e, _ in tree]
+    if mode == "sync":
+        assert threads == [caller, caller]
+    elif mode == "prefetch":
+        assert len(tree) == 2
+        assert all(t.startswith("io-prefetch") for t in threads)
+    else:  # the unpredicted batch loads on the caller's thread
+        assert threads[0].startswith("io-prefetch")
+        assert caller in threads
+    x_bytes = out[0][0].nbytes
+    for load, inner in tree:
+        assert load.attrs == {"samples": 4}
+        reads = [c for c in inner if c.name == "io.read"]
+        places = [c for c in inner if c.name == "io.place"]
+        assert len(reads) == 4
+        assert sum(c.attrs["bytes"] for c in reads) == x_bytes
+        assert len(places) == 1
+        assert places[0].attrs["bytes"] == x_bytes + out[0][1].nbytes
+    assert len(events) == sum(1 + len(inner) for _, inner in tree) + (
+        mode != "sync") * sum(e.name == "io.wait" for e in events)
+
+
+def test_loader_shards_and_bytes_across_devices(multidevice):
+    """Shards are read before they are placed, one per device as
+    ``make_array_from_callback`` asks: a sharded batch and its voxel
+    labels equal the store's contents, and a layout replicated over
+    ``model`` reads each replica's shard once per device, as a
+    per-device callback does."""
+    multidevice("""
+import numpy as np, tempfile
+from jax.sharding import PartitionSpec as P
+from repro.core import compat
+from repro.data import pipeline, store
+
+rng = np.random.default_rng(0)
+cubes = [rng.standard_normal((8, 8, 8, 2), dtype=np.float32)
+         for _ in range(4)]
+labels = [rng.integers(0, 3, (8, 8, 8)).astype(np.int32) for _ in range(4)]
+d = tempfile.mkdtemp()
+store.write_dataset(d, cubes, labels=labels)
+mesh = compat.make_mesh((2, 2), ('data', 'model'))
+ids = np.array([2, 0, 3, 1])
+want_x = np.stack([cubes[i] for i in ids])
+want_y = np.stack([labels[i] for i in ids])
+ld = pipeline.SpatialParallelLoader(
+    store.HyperslabStore(d), mesh, P('data', 'model', None, None, None),
+    global_batch=4, cache=False, label_spec=P('data', 'model', None, None))
+x, y = ld.load_batch(ids)
+assert np.array_equal(np.asarray(x), want_x)
+assert np.array_equal(np.asarray(y), want_y)
+assert x.sharding == ld.sharding and y.sharding == ld.label_sharding
+assert ld.stats.pfs_bytes == want_x.nbytes + want_y.nbytes, ld.stats
+rep = pipeline.SpatialParallelLoader(
+    store.HyperslabStore(d), mesh, P('data', None, None, None, None),
+    global_batch=4, cache=False, label_spec=P('data', None, None, None))
+x, y = rep.load_batch(ids)
+assert np.array_equal(np.asarray(x), want_x)
+assert np.array_equal(np.asarray(y), want_y)
+assert rep.stats.pfs_bytes == 2 * (want_x.nbytes + want_y.nbytes)
+print('shards ok')
+""", devices=4)

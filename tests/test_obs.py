@@ -6,6 +6,8 @@ checkpoint publish, 1F1B dispatcher threads)."""
 import dataclasses
 import json
 import os
+import re
+import sys
 import threading
 
 import jax
@@ -20,6 +22,10 @@ from repro.obs import export as export_lib
 from repro.obs import metrics as metrics_lib
 from repro.obs import trace as trace_lib
 from repro.obs import report as report_lib
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# the chip benchmark's reading of a scope, checked here on compiled steps
+from benchmarks.chip.attribution import scope_of  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -399,6 +405,70 @@ def test_prefetch_worker_and_wait_spans():
     assert spans and all(e.thread.startswith("io-prefetch") for e in spans)
     assert all(e.attrs["samples"] == 2 for e in spans)
     assert any(e.name == "io.wait" for e in tr.events())
+
+
+# ------------------------------------------------- named scopes (jit) ----
+def _instructions(hlo_text):
+    """(opcode, op_name or None) of each instruction but parameters."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%\S+ = .*? ([\w-]+)\(", line)
+        if m and m.group(1) != "parameter":
+            name = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), name.group(1) if name else None))
+    return out
+
+
+def _step_hlo(**kw):
+    cfg = _smoke()
+    sess = api_compile(RunConfig(model=cfg, global_batch=2, **kw))
+    w = cfg.input_width
+    x = jnp.ones((2, w, w, w, cfg.in_channels), jnp.float32)
+    y = jnp.ones((2, cfg.out_dim), jnp.float32)
+    text = sess._step_fn.lower(sess.params, sess.opt_state, x, y,
+                               jnp.asarray(0, jnp.int32)).compile().as_text()
+    sess.close()
+    return text
+
+
+def test_step_ops_carry_layer_scopes():
+    """The compiled step's convolutions sit under ``conv`` and its
+    max-pooling, forward and backward, under ``pool``, each inside its
+    ``block{i}``; backward ops inherit the scope through the name stack.
+    (The CPU compiler drops the metadata of the weight-gradient
+    convolutions it rewrites, so only instructions that carry an
+    ``op_name`` are checked.)"""
+    insts = _instructions(_step_hlo())
+    convs = [n for op, n in insts if op == "convolution" and n]
+    assert len(convs) >= len(_smoke().conv_channels)
+    named = {prim: [n for _, n in insts if n and n.endswith("/" + prim)]
+             for prim in ("conv_general_dilated", "reduce_window_max",
+                          "select_and_scatter")}
+    assert all(named.values()), named
+    for n in convs + named["conv_general_dilated"]:
+        assert scope_of(n) == "conv", n
+    for n in named["reduce_window_max"] + named["select_and_scatter"]:
+        assert scope_of(n) == "pool", n
+    pooled = [n for op, n in insts if op == "select-and-scatter" and n]
+    assert all(scope_of(n) == "pool" for n in pooled), pooled
+    assert any("transpose(jvp(block0))/pool/" in n
+               for n in named["select_and_scatter"])
+    assert any(n and n.split("/")[1] == "optimizer" for _, n in insts)
+
+
+def test_halo_exchange_ops_carry_halo(multidevice):
+    """At spatial=4 every collective-permute of the step is a halo
+    exchange and says so."""
+    out = multidevice(f"""
+import sys
+sys.path.insert(0, {os.path.dirname(os.path.abspath(__file__))!r})
+from test_obs import _instructions, _step_hlo, scope_of
+perms = [n for op, n in _instructions(_step_hlo(spatial=4))
+         if op.startswith("collective-permute")]
+assert perms and all(n and scope_of(n) == "halo" for n in perms), perms
+print("HALO-SCOPED", len(perms))
+""", devices=4)
+    assert "HALO-SCOPED" in out
 
 
 def test_checkpoint_spans(tmp_path):
