@@ -462,7 +462,9 @@ class Session:
         §12 input-pipeline counters ride along, summed over this
         Session's loaders: ``io_pfs_bytes`` (store bytes actually read),
         ``io_cache_hit_ratio`` (fraction of loader bytes served from the
-        distributed cache), and — when any loader prefetches —
+        distributed cache), ``io_in_place_share`` (fraction of the store
+        bytes read straight into a batch buffer), and — when any loader
+        prefetches —
         ``io_stall_s`` (residual time steps still blocked on a queued
         batch) and ``io_queue_occupancy`` (mean prefetch-queue depth at
         serve time; ~depth when the pipeline keeps up).
@@ -489,6 +491,10 @@ class Session:
                 + ld.stats.cache_bytes_redistributed for ld in self._loaders)
             out["io_cache_hit_ratio"] = (
                 1.0 - out["io_pfs_bytes"] / served if served else 0.0)
+            in_place = sum(ld.stats.bytes_in_place for ld in self._loaders)
+            out["io_in_place_share"] = (
+                in_place / out["io_pfs_bytes"] if out["io_pfs_bytes"]
+                else 0.0)
             async_loaders = [ld for ld in self._loaders
                              if hasattr(ld, "queue_occupancy")]
             if async_loaders:

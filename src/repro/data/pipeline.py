@@ -22,6 +22,12 @@ Key ideas reproduced:
     the shard's cache. Reads stay hyperslab-exact: the served array is
     always the exact requested slab; only the *read* (and the cache
     entry, and the PFS byte count) covers the margin.
+ 5. *In-place batches*: each shard of a batch is a host buffer shaped
+    like the shard, and each sample's read lands in its row — straight
+    from the store when the cache is off and there is no margin
+    (``IOStats.bytes_in_place``), else copied from the cache entry or
+    the widened read. The loader reuses the buffers (``BatchBuffers``)
+    once the arrays placed from them are ready (DESIGN.md §12).
 
 The loader is thread-safe: a ``PrefetchLoader`` (``data/prefetch.py``)
 calls ``load_batch`` from worker threads, so cache and counter mutations
@@ -33,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Dict, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -49,17 +55,61 @@ class IOStats:
     cache_bytes_local: int = 0
     cache_bytes_redistributed: int = 0
     label_fetches: int = 0  # store.target() reads (not served by cache)
+    bytes_in_place: int = 0  # store bytes read straight into a batch buffer
 
     def reset(self):
         self.pfs_bytes = self.cache_bytes_local = 0
         self.cache_bytes_redistributed = 0
         self.label_fetches = 0
+        self.bytes_in_place = 0
 
     def cache_hit_ratio(self) -> float:
         """Fraction of loader bytes served from the distributed cache."""
         hit = self.cache_bytes_local + self.cache_bytes_redistributed
         total = hit + self.pfs_bytes
         return hit / total if total else 0.0
+
+
+class BatchBuffers:
+    """Host batch buffers, reused from batch to batch. ``take`` lends one
+    out; ``give`` returns a batch's buffers with the array placed from
+    them. A returned buffer is lent again only once that array reports
+    ready (its host-to-device transfer has ended), and never when the
+    array aliases it (a CPU device may place a host array without a
+    copy) or its holder deleted it. Not thread-safe: the loader calls it
+    under its lock."""
+
+    def __init__(self):
+        # (shape, dtype) -> [(buffer, the array placed from it)]
+        self._free: Dict[Tuple, List[Tuple[np.ndarray, jax.Array]]] = {}
+
+    def take(self, shape: Tuple[int, ...], dtype) -> np.ndarray:
+        pool = self._free.get((shape, np.dtype(dtype)), [])
+        # an array its holder deleted no longer says when its transfer
+        # ended (and asking it would crash): its buffer leaves the pool
+        pool[:] = [e for e in pool if not e[1].is_deleted()]
+        for j, (buf, placed) in enumerate(pool):
+            if placed.is_ready():
+                del pool[j]
+                return buf
+        return np.empty(shape, dtype)
+
+    def give(self, bufs: Iterable[np.ndarray], placed: jax.Array) -> None:
+        bufs = list(bufs)
+        if _aliases(placed, bufs):
+            return
+        for buf in bufs:
+            self._free.setdefault((buf.shape, buf.dtype), []).append(
+                (buf, placed))
+
+
+def _aliases(placed: jax.Array, bufs: List[np.ndarray]) -> bool:
+    """Whether a shard of ``placed`` on a host-memory (CPU) device uses
+    the memory of one of ``bufs``."""
+    spans = [(b.ctypes.data, b.ctypes.data + b.nbytes) for b in bufs]
+    return any(lo <= shard.data.unsafe_buffer_pointer() < hi
+               for shard in placed.addressable_shards
+               if shard.device.platform == "cpu" for lo, hi in spans)
 
 
 class SpatialParallelLoader:
@@ -93,6 +143,7 @@ class SpatialParallelLoader:
         self.stats = IOStats()
         self.epoch = 0
         self._lock = threading.Lock()
+        self._buffers = BatchBuffers()  # taken and given under _lock
         self._rank_of = {d: i for i, d in enumerate(self.mesh.devices.flat)}
 
     # ------------------------------------------------------------ sched ----
@@ -122,10 +173,13 @@ class SpatialParallelLoader:
         return tuple(out) + slab[len(dims):]
 
     def _fetch(self, sample: int, slab: Tuple[slice, ...], device_rank: int,
-               what: str = "x") -> np.ndarray:
-        """One hyperslab, from the distributed cache or the store. The
-        read (and cache entry) covers the ``halo_voxels``-expanded slab;
-        the returned array is always the exact requested slab."""
+               what: str, out: np.ndarray) -> None:
+        """Write one hyperslab into ``out`` (a row of a batch buffer),
+        from the distributed cache or the store. With the cache off and
+        no halo margin the store reads straight into ``out``; otherwise
+        the read (and cache entry) covers the ``halo_voxels``-expanded
+        slab in an array of its own, never a view of a reused buffer,
+        and the exact requested slab is copied into ``out``."""
         dims = self.store.sample_shape[:3]
         wide = self._expand(slab, dims)
         key = (sample, what) + tuple((s.start, s.stop) for s in wide)
@@ -138,23 +192,30 @@ class SpatialParallelLoader:
                     self.stats.cache_bytes_local += arr.nbytes
                 else:
                     self.stats.cache_bytes_redistributed += arr.nbytes
+        elif wide is slab and not self.cache_enabled:
+            with trace_lib.span("io.read") as span:
+                self.store.read_hyperslab_into(sample, slab, out, what)
+                span.set(bytes=out.nbytes, in_place=True)
+            with self._lock:
+                self.stats.pfs_bytes += out.nbytes
+                self.stats.bytes_in_place += out.nbytes
+            return
         else:
             with trace_lib.span("io.read") as span:
                 arr = self.store.read_hyperslab(sample, wide, what)
-                span.set(bytes=arr.nbytes)
+                span.set(bytes=arr.nbytes, in_place=False)
             with self._lock:
                 self.stats.pfs_bytes += arr.nbytes
                 if self.cache_enabled:
                     self._cache[key] = (device_rank, arr)
-        if wide is slab:
-            return arr
-        inner = tuple(
-            slice((0 if s.start is None else s.start) - w.start,
-                  (0 if s.start is None else s.start) - w.start
-                  + ((dim if s.stop is None else s.stop)
-                     - (0 if s.start is None else s.start)))
-            for s, w, dim in zip(slab, wide, dims))
-        return arr[inner]
+        if wide is not slab:
+            arr = arr[tuple(
+                slice((0 if s.start is None else s.start) - w.start,
+                      (0 if s.start is None else s.start) - w.start
+                      + ((dim if s.stop is None else s.stop)
+                         - (0 if s.start is None else s.start)))
+                for s, w, dim in zip(slab, wide, dims))]
+        np.copyto(out, arr)
 
     @staticmethod
     def _slab_key(idx: Tuple[slice, ...], shape) -> Tuple:
@@ -175,24 +236,30 @@ class SpatialParallelLoader:
 
     def _read_shards(self, sample_ids: np.ndarray, shape, sharding,
                      what: str) -> Tuple[Dict[Tuple, np.ndarray], int]:
-        """Read and stack each addressable device's shard of the batch,
-        keyed by ``_slab_key``, before anything is placed; and the bytes
-        the placement will move. One shard per device, in the order
+        """Read each addressable device's shard of the batch into a host
+        buffer shaped like the shard, each sample into its row, keyed by
+        ``_slab_key``, before anything is placed; and the bytes the
+        placement will move. One shard per device, in the order
         ``make_array_from_callback`` asks for them (once when the
-        sharding is fully replicated)."""
+        sharding is fully replicated). The buffers come from the
+        loader's pool; ``_load_batch`` gives them back once placed."""
         ranks = self._rank_map(shape, sharding)
         devices = sharding.addressable_devices_indices_map(tuple(shape))
         indices = ([(slice(None),) * len(shape)]
                    if sharding.is_fully_replicated else devices.values())
+        dtype = self.store.dtype(what)
         ready: Dict[Tuple, np.ndarray] = {}
         for idx in indices:
             # idx[0] selects samples; idx[1:4] is the spatial hyperslab.
             key = self._slab_key(idx, shape)
             slab = tuple(idx[1:]) if what == "y" else (
                 tuple(idx[1:-1]) + (slice(None),))
-            ready[key] = np.stack(
-                [self._fetch(int(s), slab, ranks[key], what)
-                 for s in sample_ids[idx[0]]], axis=0)
+            if key not in ready:
+                with self._lock:
+                    ready[key] = self._buffers.take(
+                        tuple(hi - lo for lo, hi in key), dtype)
+            for row, s in zip(ready[key], sample_ids[idx[0]]):
+                self._fetch(int(s), slab, ranks[key], what, row)
         placed = sum(ready[self._slab_key(idx, shape)].nbytes
                      for idx in devices.values())
         return ready, placed
@@ -253,6 +320,10 @@ class SpatialParallelLoader:
             x = self._place(shape, self.sharding, xs)
             y = (self._place(lshape, self.label_sharding, ys) if voxel
                  else self._place_targets(sample_ids, tg))
+        with self._lock:
+            self._buffers.give(xs.values(), x)
+            if voxel:
+                self._buffers.give(ys.values(), y)
         return x, y
 
     def close(self) -> None:

@@ -411,3 +411,256 @@ assert np.array_equal(np.asarray(y), want_y)
 assert rep.stats.pfs_bytes == 2 * (want_x.nbytes + want_y.nbytes)
 print('shards ok')
 """, devices=4)
+
+
+# ------------------------------------------------- in-place batches ----
+def _stacked(root, ids, what="x"):
+    """The plain reference: each sample's whole file, stacked."""
+    return np.stack([np.load(f"{root}/{what}_{int(i):06d}.npy")
+                     for i in ids])
+
+
+_IN_PLACE_CASES = {  # cache, halo margin, voxel labels, in-place share
+    "whole": (False, 0, False, 1.0),
+    "halo": (False, 1, False, 0.0),
+    "cache": (True, 0, False, 0.0),
+    "voxel": (False, 0, True, 1.0),
+}
+
+
+@pytest.mark.parametrize("case", list(_IN_PLACE_CASES))
+def test_in_place_batches_bitwise_equal_stacked_loads(tmp_path, case):
+    """Two shuffled epochs of batches read into the loader's reused
+    buffers equal ``np.stack`` of plain ``np.load`` reads, bit for bit:
+    straight from the store, through the halo margin's widened read, from
+    the cache (epoch 1 reads nothing), and with voxel labels."""
+    cache, halo, voxel, share = _IN_PLACE_CASES[case]
+    root = str(tmp_path)
+    label_spec = None
+    if voxel:
+        cubes, labels = synthetic.make_segmentation_dataset(
+            8, 16, channels=2, seed=0)
+        store.write_dataset(root, cubes, labels=labels)
+        label_spec = P("data", "model", None, None)
+    else:
+        _dataset(root)
+    ld = pipeline.SpatialParallelLoader(
+        store.HyperslabStore(root), _mesh11(), SPEC, global_batch=4,
+        seed=3, cache=cache, label_spec=label_spec, halo_voxels=halo)
+    for epoch in range(2):
+        before = ld.stats.pfs_bytes
+        order = ld.epoch_schedule()
+        for lo in range(0, 8, 4):
+            ids = order[lo:lo + 4]
+            x, y = ld.load_batch(ids)
+            assert np.array_equal(np.asarray(x), _stacked(root, ids))
+            if voxel:
+                assert np.array_equal(np.asarray(y), _stacked(root, ids, "y"))
+        if cache and epoch == 1:
+            assert ld.stats.pfs_bytes == before  # all from the cache
+    assert ld.stats.bytes_in_place == share * ld.stats.pfs_bytes
+
+
+_SHARDED_SCRIPT = """
+import numpy as np, tempfile
+from jax.sharding import PartitionSpec as P
+from repro.core import compat
+from repro.data import pipeline, store, synthetic
+
+d = tempfile.mkdtemp()
+cubes, targets = synthetic.make_cosmology_dataset(8, 16, channels=2, seed=0)
+store.write_dataset(d, cubes, targets)
+mesh = compat.make_mesh((1, {n}), ('data', 'model'))
+ld = pipeline.SpatialParallelLoader(
+    store.HyperslabStore(d), mesh, P({spec}), global_batch=4, seed=1,
+    cache=False)
+for _ in range(2):
+    order = ld.epoch_schedule()
+    for lo in range(0, 8, 4):
+        ids = order[lo:lo + 4]
+        x, _ = ld.load_batch(ids)
+        assert x.sharding == ld.sharding
+        assert np.array_equal(np.asarray(x),
+                              np.stack([cubes[i] for i in ids]))
+assert ld.stats.bytes_in_place == ld.stats.pfs_bytes > 0, ld.stats
+print('in place ok')
+"""
+
+
+@pytest.mark.parametrize("n,spec", [
+    (2, "'data', 'model', None, None, None"),   # depth: one byte range
+    (4, "'data', 'model', None, None, None"),
+    (2, "'data', None, 'model', None, None"),   # H: the copy fallback
+], ids=["depth2", "depth4", "height2"])
+def test_in_place_sharded_batches_bitwise_equal(multidevice, n, spec):
+    """Depth-sharded shards (each one ``readinto``) and H-sliced shards
+    (copied out of a memory map) land in their buffers bit for bit."""
+    assert "in place ok" in multidevice(
+        _SHARDED_SCRIPT.format(n=n, spec=spec), devices=n)
+
+
+@pytest.mark.parametrize("pf", [0, 2], ids=["sync", "prefetch"])
+def test_held_batch_unchanged_by_later_loads(tmp_path, pf):
+    """The buffer-ownership rule: a batch the caller still holds is not
+    overwritten when the loader reuses host buffers for the next two."""
+    root = _dataset(str(tmp_path))
+    ld = _loader(root, seed=4, cache=False, pf=pf)
+    try:
+        order = ld.schedule_for_epoch(0)
+        held = [ld.load_batch(order[lo:lo + 4]) for lo in (0, 4)]
+        ld.load_batch(order[:4])
+        ld.load_batch(order[4:8])
+        for lo, (x, _) in zip((0, 4), held):
+            assert np.array_equal(np.asarray(x),
+                                  _stacked(root, order[lo:lo + 4]))
+    finally:
+        ld.close()
+
+
+class _Placed:
+    """Stands in for a placed array whose transfer may still run."""
+
+    def __init__(self):
+        self.ready = self.deleted = False
+        self.addressable_shards = []
+
+    def is_ready(self):
+        return self.ready
+
+    def is_deleted(self):
+        return self.deleted
+
+
+def test_batch_buffers_lend_only_ready_unaliased():
+    """A buffer is lent again only once the array placed from it reports
+    ready, and never when the array uses its memory (a CPU placement
+    without a copy) or was deleted."""
+    pool = pipeline.BatchBuffers()
+    a = pool.take((2, 3), np.float32)
+    placed = _Placed()
+    pool.give([a], placed)
+    b = pool.take((2, 3), np.float32)
+    assert b is not a  # a's transfer has not ended: a new buffer
+    assert pool.take((3, 2), np.float32) is not a  # other shapes never
+    placed.ready = True
+    assert pool.take((2, 3), np.float32) is a
+    gone = _Placed()
+    gone.ready = gone.deleted = True
+    pool.give([b], gone)
+    assert pool.take((2, 3), np.float32) is not b
+    # a 4 KiB-aligned host array is placed on the CPU without a copy
+    raw = np.empty(4096 + 2 * 4096, np.uint8)
+    off = -raw.ctypes.data % 4096
+    c = raw[off:off + 4096].view(np.float32).reshape(8, 128)
+    x = jax.block_until_ready(jax.device_put(c))
+    assert x.addressable_shards[0].data.unsafe_buffer_pointer() \
+        == c.ctypes.data
+    pool.give([c], x)
+    assert pool.take(c.shape, c.dtype) is not c  # c now belongs to x
+
+
+@pytest.mark.parametrize("cache,halo,share", [
+    (False, 0, 1.0), (True, 0, 0.0), (False, 1, 0.0)],
+    ids=["streaming", "cached", "halo"])
+def test_session_io_in_place_share(tmp_path, cache, halo, share):
+    """``io_in_place_share``: 1.0 when every store read lands in a batch
+    buffer (cache off, no margin); 0 through the cache, over a cached
+    epoch too, and with a halo margin."""
+    from repro.api import compile as api_compile
+    root = _dataset(str(tmp_path), n=4, w=16)
+    sess = api_compile(_smoke_config(data_dir=root))
+    try:
+        ld = sess.make_loader(cache=cache, prefetch=0, halo_voxels=halo)
+        for _ in range(2):
+            order = ld.epoch_schedule()
+            for lo in range(0, 4, 2):
+                ld.load_batch(order[lo:lo + 2])
+        tele = sess.telemetry()
+        assert tele["io_in_place_share"] == share
+        assert tele["io_pfs_bytes"] > 0
+    finally:
+        sess.close()
+
+
+# --------------------------------------------------------- store reads ----
+_SLABS = {
+    "full": (slice(None),) * 4,
+    "depth": (slice(4, 12), slice(None), slice(None), slice(None)),
+    "height": (slice(None), slice(3, 9), slice(None), slice(None)),
+}
+
+
+@pytest.mark.parametrize("name", list(_SLABS))
+def test_read_hyperslab_and_into_agree(tmp_path, name):
+    """``read_hyperslab`` (a fresh array) and ``read_hyperslab_into`` (a
+    caller's array, here a row of a batch buffer holding stale bytes)
+    read the same fragment, one byte range or through the copy."""
+    root = _dataset(str(tmp_path), n=2)
+    s = store.HyperslabStore(root)
+    slab = _SLABS[name]
+    want = np.load(f"{root}/x_000001.npy")[slab]
+    fresh = s.read_hyperslab(1, slab)
+    buf = np.full((2,) + want.shape, np.nan, np.float32)
+    row = buf[1]
+    assert s.read_hyperslab_into(1, slab, row) is row
+    assert np.array_equal(fresh, want) and np.array_equal(buf[1], want)
+    assert np.isnan(buf[0]).all()  # only its own row was written
+    assert s.bytes_read == 2 * want.nbytes and s.reads == 2
+    with pytest.raises(ValueError, match="destination"):
+        s.read_hyperslab_into(1, slab, np.empty(want.shape, np.float64))
+
+
+def test_in_place_transient_fault_retried_identical_batch(tmp_path):
+    """A ``loader.read`` transient fired on the in-place path is retried
+    by the store and gives the identical batch."""
+    root = _dataset(str(tmp_path))
+    ids = np.array([5, 1, 6, 2])
+    clean, _ = _loader(root, cache=False).load_batch(ids)
+    ld = _loader(root, cache=False)
+    with faults.active(faults.FaultSpec("loader.read", at_calls=(1, 2),
+                                        max_fires=2)):
+        x, _ = ld.load_batch(ids)
+    assert ld.store.retries == 2
+    assert ld.stats.bytes_in_place == ld.stats.pfs_bytes
+    assert np.array_equal(np.asarray(x), np.asarray(clean))
+
+
+def test_truncated_npy_retried_then_healed(tmp_path, monkeypatch):
+    """A file shorter than its header says fails the attempt with an
+    ``OSError``; the retry after the backoff reads the whole slab."""
+    root = _dataset(str(tmp_path))
+    path = f"{root}/x_000003.npy"
+    whole = open(path, "rb").read()
+    with open(path, "r+b") as f:
+        f.truncate(len(whole) // 2)
+
+    def heal(_):
+        with open(path, "wb") as f:
+            f.write(whole)
+
+    monkeypatch.setattr(store.time, "sleep", heal)
+    ld = _loader(root, cache=False)
+    x, _ = ld.load_batch(np.array([3, 0, 1, 2]))
+    assert ld.store.retries == 1
+    assert np.array_equal(np.asarray(x), _stacked(root, [3, 0, 1, 2]))
+
+
+@pytest.mark.parametrize("name", list(_SLABS))
+def test_truncated_npy_raises_store_read_error(tmp_path, monkeypatch, name):
+    """A truncated ``.npy`` raises ``StoreReadError`` naming the file after
+    the capped attempts, into a caller's array or a fresh one."""
+    root = _dataset(str(tmp_path), n=2)
+    path = f"{root}/x_000001.npy"
+    with open(path, "r+b") as f:
+        f.truncate(200)
+    monkeypatch.setattr(store.time, "sleep", lambda _: None)
+    s = store.HyperslabStore(root)
+    out = np.empty(np.load(f"{root}/x_000000.npy")[_SLABS[name]].shape,
+                   np.float32)
+    for read in (lambda: s.read_hyperslab_into(1, _SLABS[name], out),
+                 lambda: s.read_hyperslab(1, _SLABS[name])):
+        with pytest.raises(StoreReadError, match="x_000001.npy") as ei:
+            read()
+        assert ei.value.attempts == store.MAX_READ_ATTEMPTS
+    assert s.retries == 2 * (store.MAX_READ_ATTEMPTS - 1)
+    assert s.reads == 0

@@ -317,8 +317,8 @@ def _capture_absorb(monkeypatch):
 
 _TELEMETRY_KEYS = ("steps", "skipped_steps", "loss_scale",
                    "loader_retries", "resumes")
-_IO_KEYS = ("io_pfs_bytes", "io_cache_hit_ratio", "io_stall_s",
-            "io_queue_occupancy")
+_IO_KEYS = ("io_pfs_bytes", "io_cache_hit_ratio", "io_in_place_share",
+            "io_stall_s", "io_queue_occupancy")
 
 
 def test_telemetry_survives_registry_migration_bitwise(monkeypatch):
