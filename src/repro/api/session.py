@@ -458,6 +458,10 @@ class Session:
         absorbed by backoff, summed over this Session's loaders), and
         ``resumes`` (checkpoint auto-resumes, set by the supervisor).
         Reading ``skipped_steps`` syncs the lazy accumulator.
+        ``pool_index_share`` is the share of the model's max-pools that
+        lower through the saved window index under the plan
+        (``core/spatial_conv.py``), 0 where all fall back to
+        ``reduce_window``.
 
         §12 input-pipeline counters ride along, summed over this
         Session's loaders: ``io_pfs_bytes`` (store bytes actually read),
@@ -482,7 +486,9 @@ class Session:
                "skipped_steps": round(skipped),
                "loss_scale": scale,
                "loader_retries": float(retries),
-               "resumes": float(self.resumes)}
+               "resumes": float(self.resumes),
+               "pool_index_share": memory_lib.pool_index_share(self.cfg,
+                                                               self.plan)}
         if self._loaders:
             out["io_pfs_bytes"] = float(
                 sum(ld.stats.pfs_bytes for ld in self._loaders))

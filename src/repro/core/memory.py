@@ -47,20 +47,25 @@ import jax
 
 from repro.configs.base import ConvNetConfig
 from repro.core import perf_model, precision as precision_lib
+from repro.core.spatial_conv import index_pool_applies
 
 # Structural coefficients, calibrated once against the jaxpr-liveness
 # measurement over {cosmoflow W16/W32, unet} x {fp32, bf16} x {remat
-# on/off} (max error 12%; tests pin model-vs-measured within 15%):
+# on/off} (max error 13%; tests pin model-vs-measured within 15%):
 #
 # _SAVED_PER_BLOCK — float residuals a conv block keeps per output-sized
 # tensor beyond its input: the conv output (for the BN backward) and the
-# activation output (for the pooling / next conv backward).
+# activation output (for the pooling / next conv backward). A block whose
+# max-pool takes the saved-index path keeps a uint8 per pool window in
+# place of the activation output (``_saved_bytes``).
 _SAVED_PER_BLOCK = 2.0
 # _WORKING_SET_COPIES — concurrent output-sized copies while one block's
 # forward+backward is in flight (padded conv operands, BN intermediates,
 # select masks, cotangents). The liveness scans show ~4-5 copies of the
 # largest block's output at the peak program point.
 _WORKING_SET_COPIES = 4.25
+# Every model's max-pool: window = stride = 2.
+_POOL_WINDOW = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,6 +144,46 @@ def _stage_divisors(plan, st) -> Tuple[int, int]:
     return vox, batch
 
 
+def _index_pooled(plan, l, st) -> bool:
+    """Whether conv layer ``l``'s max-pool, under stage ``st``, lowers
+    through the saved window index (``spatial_conv.maxpool3d``)."""
+    if not l.pooled:
+        return False
+    w = l.width // l.stride
+    return index_pool_applies(
+        [w // plan.degree(a) if a else w for a in st.spatial_axes],
+        _POOL_WINDOW, _POOL_WINDOW)
+
+
+def pool_index_share(cfg: ConvNetConfig, plan) -> float:
+    """Share of the model's max-pools that lower through the saved window
+    index under ``plan``; 0 where all fall back or there are none."""
+    pooled = [_index_pooled(plan, l, st)
+              for l, st in _plan_entries(cfg, plan)
+              if l is not None and l.pooled]
+    return sum(pooled) / len(pooled) if pooled else 0.0
+
+
+def _saved_bytes(plan, l, st, act_bytes: int) -> float:
+    """Bytes a conv block keeps for its backward per output element,
+    beyond its input: ``_SAVED_PER_BLOCK`` activation-sized tensors, or,
+    where its pool takes the saved-index path, the conv output and one
+    uint8 per pool window. (A U-Net skip needs no copy of its own: the
+    decoder's backward keeps the concatenation it feeds.)"""
+    if _index_pooled(plan, l, st):
+        return (_SAVED_PER_BLOCK - 1) * act_bytes + 1 / _POOL_WINDOW ** 3
+    return _SAVED_PER_BLOCK * act_bytes
+
+
+def _pair_second(cfg: ConvNetConfig, idx: int) -> bool:
+    """Whether ``_plan_entries`` entry ``idx`` is the second conv of a
+    U-Net conv pair, whose input a checkpointed pair recomputes."""
+    if cfg.arch == "cosmoflow":
+        return False
+    pairs = 2 * cfg.depth + 2   # encoder levels and bottleneck
+    return idx % 2 == 1 if idx < pairs else (idx - pairs) % 3 == 2
+
+
 def plan_peak_bytes(
     cfg: ConvNetConfig,
     plan,
@@ -154,10 +199,11 @@ def plan_peak_bytes(
     The activation peak of reverse-mode AD: every saved-for-backward
     residual resident at once, plus the working set of the block whose
     forward/backward is in flight. Per conv block the residuals are the
-    block *input* (for the filter gradient) plus ``_SAVED_PER_BLOCK``
-    output-sized tensors (conv output for the BN backward, activation
-    output for the pooling backward), all under the stage's sharding. A
-    ``remat`` stage keeps only each block's input and re-materializes
+    block *input* (for the filter gradient) plus ``_saved_bytes`` per
+    output element (conv output for the BN backward, activation output
+    for the pooling backward or the pool's window index), all under the
+    stage's sharding. A ``remat`` stage keeps only each block's input (a
+    U-Net block is a conv pair) and re-materializes
     the internals transiently inside the backward (they move into the
     ``workspace`` term, alongside the ``_WORKING_SET_COPIES`` every
     in-flight block pays).
@@ -179,7 +225,7 @@ def plan_peak_bytes(
     resident = 0.0   # saved-for-backward residuals
     transient = 0.0  # max recompute/backward working set
     entries = _plan_entries(cfg, plan)
-    for l, st in entries:
+    for idx, (l, st) in enumerate(entries):
         vox_div, batch_div = _stage_divisors(plan, st)
         b_local = global_batch / max(batch_div, 1)
         if l is None:
@@ -193,10 +239,13 @@ def plan_peak_bytes(
         n_in = l.width ** 3 / vox_div
         n_out = (l.width // l.stride) ** 3 / vox_div
         saved_in = n_in * l.cin * b_local * act_bytes
-        internals = _SAVED_PER_BLOCK * n_out * l.cout * b_local * act_bytes
+        internals = (_saved_bytes(plan, l, st, act_bytes) * n_out * l.cout
+                     * b_local)
         working = _WORKING_SET_COPIES * n_out * l.cout * b_local * act_bytes
-        resident += saved_in
-        if getattr(st, "remat", False):
+        remat = getattr(st, "remat", False)
+        if not (remat and _pair_second(cfg, idx)):
+            resident += saved_in
+        if remat:
             # internals recomputed transiently inside this block's remat
             # backward, on top of the block's normal working set
             transient = max(transient, working + internals)
@@ -288,8 +337,9 @@ def _pipeline_peak_bytes(
             n_out = (l.width // l.stride) ** 3 / vox_div
             # recompute backward: the segment's saved set at ONE micro,
             # plus the working set of whichever block is in flight
-            transient += (n_in * l.cin + _SAVED_PER_BLOCK * n_out
-                          * l.cout) * b_micro * act_bytes
+            transient += (n_in * l.cin * act_bytes
+                          + _saved_bytes(plan, l, st, act_bytes)
+                          * n_out * l.cout) * b_micro
             work_max = max(work_max, _WORKING_SET_COPIES * n_out
                            * l.cout * b_micro * act_bytes)
             if cfg.arch == "unet" and idx < 2 * depth and idx % 2 == 1:

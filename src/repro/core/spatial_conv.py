@@ -28,7 +28,9 @@ cuDNN NCDHW). The partitioned dims are identified by mesh-axis names in a
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+import functools
+import itertools
+from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -216,6 +218,66 @@ def deconv3d(
     )
 
 
+def index_pool_applies(widths: Sequence[int], window: int,
+                       stride: int) -> bool:
+    """Whether ``maxpool3d`` lowers through the saved window index for
+    local D/H/W ``widths``: windows that tile each local dim exactly, with
+    an offset that fits a uint8."""
+    return window == stride and window ** 3 <= 256 and all(
+        n % window == 0 for n in widths)
+
+
+def _window_slices(x: jax.Array, window: int) -> Iterator[jax.Array]:
+    """The window^3 strided sub-volumes of ``x``, one per offset in
+    row-major (d, h, w) window order."""
+    n, d, h, w, c = x.shape
+    for a, b, e in itertools.product(range(window), repeat=3):
+        yield lax.slice(x, (0, a, b, e, 0), (n, d, h, w, c),
+                        (1, window, window, window, 1))
+
+
+def _pool_with_index(x: jax.Array, window: int):
+    """Non-overlapping max pool in one pass over the strided sub-volumes:
+    the max, bit for bit ``reduce_window``'s, and the uint8 offset of the
+    FIRST maximum of each window, select-and-scatter's ``ge`` rule."""
+    y = index = None
+    for k, sub in enumerate(_window_slices(x, window)):
+        if y is None:
+            y, index = sub, jnp.zeros(sub.shape, jnp.uint8)
+        else:
+            index = jnp.where(sub > y, jnp.uint8(k), index)
+            y = lax.max(y, sub)
+    return y, index
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _maxpool_index(x: jax.Array, window: int) -> jax.Array:
+    return _pool_with_index(x, window)[0]
+
+
+def _maxpool_index_bwd(window, index, g):
+    """Each window's gradient lands on its saved offset: ``g`` and the
+    index repeated over the window, compared with a window iota. No
+    scatter, and no copy of ``x`` kept for it. H is repeated first: where
+    XLA puts H in the TPU's lanes, as in CosmoFlow's step, that order
+    costs one small relayout instead of three at full size. The ops carry
+    the caller's name stack (``transpose(jvp(...))/pool/``)."""
+    def spread(t):
+        for axis in (2, 3, 1):
+            t = jnp.repeat(t, window, axis)
+        return t
+
+    dx = spread(g)
+    offset = sum((jnp.arange(dx.shape[dim]) % window * window ** (3 - dim))
+                 .astype(jnp.uint8).reshape((-1,) + (1,) * (4 - dim))
+                 for dim in _SPATIAL_DIMS)
+    dx = jnp.where(spread(index) == offset, dx, jnp.zeros((), g.dtype))
+    return (dx,)
+
+
+_maxpool_index.defvjp(_pool_with_index, _maxpool_index_bwd)
+
+
 def maxpool3d(
     x: jax.Array,
     part: SpatialPartitioning,
@@ -224,10 +286,17 @@ def maxpool3d(
     overlap: Optional[bool] = None,
 ) -> jax.Array:
     """Distributed max pooling. For window == stride (the paper's pooling)
-    no halo is required when local widths divide the stride. When a halo IS
-    needed, the ``overlap_halo`` flag selects the packed exchange (minimal
-    ppermutes) over the legacy blocking one; pooling is too cheap to be
-    worth an interior/boundary split."""
+    no halo is required when local widths divide the stride; the pool then
+    saves each window's argmax as a uint8 index and routes the gradient
+    through it (DESIGN.md §2) rather than autodiff's select-and-scatter.
+    Any other case (window != stride, or local widths the stride does not
+    divide) lowers to ``reduce_window``. When a halo IS needed, the
+    ``overlap_halo`` flag selects the packed exchange (minimal ppermutes)
+    over the legacy blocking one; pooling is too cheap to be worth an
+    interior/boundary split."""
+    if index_pool_applies([x.shape[d] for d in _SPATIAL_DIMS], window,
+                          stride):
+        return _maxpool_index(x, window)
     if overlap is None:
         overlap = flags.get("overlap_halo")
     lo, hi = halo_lib.conv_halo_widths(window, stride)

@@ -176,6 +176,50 @@ print("OK")
 """, devices=8)
 
 
+def test_cosmoflow_gradient_spatial4_matches_single_device(multidevice):
+    """At spatial=4 every local depth the pools see is even, so they take
+    the saved-window-index path on each shard (no select-and-scatter in
+    the step); one SGD step at lr 1 with no momentum reads the gradient
+    back (p0 - p1), which must match the one-device gradient."""
+    multidevice("""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import compat
+from repro import configs
+from repro.models import cosmoflow
+from repro.optim.adam import SGD, constant
+from repro.train.train_step import make_convnet_train_step
+
+cfg = configs.get_smoke_config('cosmoflow-512')
+gb = 2
+W = cfg.input_width
+x = jax.random.normal(jax.random.PRNGKey(0), (gb, W, W, W, cfg.in_channels))
+y = jax.random.normal(jax.random.PRNGKey(1), (gb, cfg.out_dim))
+params0 = cosmoflow.init_params(jax.random.PRNGKey(2), cfg)
+
+grads, losses = [], []
+for shape in ((1, 1), (1, 4)):
+    mesh = compat.make_mesh(shape, ('data', 'model'))
+    opt = SGD(lr=constant(1.0), momentum=0.0)
+    step = make_convnet_train_step(cfg, mesh, opt,
+        spatial_axes=('model', None, None), data_axes=('data',),
+        global_batch=gb)
+    args = (jax.tree.map(jnp.copy, params0), opt.init(params0), x, y,
+            jnp.asarray(3, jnp.int32))
+    assert 'select_and_scatter' not in step.lower(*args).as_text()
+    p, _, loss = step(*args)
+    grads.append({k: np.asarray(params0[k]) - np.asarray(p[k]) for k in p})
+    losses.append(float(loss))
+
+assert abs(losses[0] - losses[1]) < 2e-5, losses
+for k in grads[0]:
+    g1, g4 = grads[0][k], grads[1][k]
+    scale = max(np.abs(g1).max(), 1e-6)
+    np.testing.assert_allclose(g4 / scale, g1 / scale, rtol=0, atol=1e-4,
+                               err_msg=k)
+print("OK")
+""", devices=4)
+
+
 def test_lm_gspmd_matches_single_device(multidevice):
     """TP-sharded transformer train step == unsharded (GSPMD transparency)."""
     multidevice("""

@@ -22,6 +22,7 @@ from repro.obs import export as export_lib
 from repro.obs import metrics as metrics_lib
 from repro.obs import trace as trace_lib
 from repro.obs import report as report_lib
+from repro.models import cosmoflow
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # the chip benchmark's reading of a scope, checked here on compiled steps
@@ -316,15 +317,16 @@ def _capture_absorb(monkeypatch):
 
 
 _TELEMETRY_KEYS = ("steps", "skipped_steps", "loss_scale",
-                   "loader_retries", "resumes")
+                   "loader_retries", "resumes", "pool_index_share")
 _IO_KEYS = ("io_pfs_bytes", "io_cache_hit_ratio", "io_in_place_share",
             "io_stall_s", "io_queue_occupancy")
 
 
 def test_telemetry_survives_registry_migration_bitwise(monkeypatch):
     """spatial=1, pipeline off, with a prefetching loader: the full §11
-    + §12 key set passes through the MetricsRegistry unchanged — same
-    keys, same order, same values, same types."""
+    + §12 key set (with ``pool_index_share``) passes through the
+    MetricsRegistry unchanged — same keys, same order, same values, same
+    types."""
     cap = _capture_absorb(monkeypatch)
     sess = api_compile(RunConfig(model=_smoke(), global_batch=2,
                                  guard=True))
@@ -366,8 +368,9 @@ x, y = sess._synthetic_batch()
 sess.step((x, y))
 tel = sess.telemetry()
 expect = {{'steps', 'skipped_steps', 'loss_scale', 'loader_retries',
-           'resumes'}}
+           'resumes', 'pool_index_share'}}
 assert set(tel) == expect, sorted(tel)
+assert tel['pool_index_share'] == 1.0, tel
 assert list(cap['in']) == list(cap['out']) == list(tel)
 for k in cap['in']:
     assert type(cap['out'][k]) is type(cap['in'][k]), k
@@ -434,26 +437,54 @@ def _step_hlo(**kw):
 def test_step_ops_carry_layer_scopes():
     """The compiled step's convolutions sit under ``conv`` and its
     max-pooling, forward and backward, under ``pool``, each inside its
-    ``block{i}``; backward ops inherit the scope through the name stack.
-    (The CPU compiler drops the metadata of the weight-gradient
-    convolutions it rewrites, so only instructions that carry an
-    ``op_name`` are checked.)"""
+    ``block{i}``; backward ops inherit the scope through the name stack,
+    the pool's custom backward included. The pools take the saved-index
+    path, so the step holds no select-and-scatter: inside a block only
+    that path emits strided slices and ``gt`` compares (forward) and
+    ``eq`` compares (backward), and each carries ``pool``. (The CPU
+    compiler drops the metadata of the weight-gradient convolutions it
+    rewrites, so only instructions that carry an ``op_name`` are
+    checked.)"""
     insts = _instructions(_step_hlo())
     convs = [n for op, n in insts if op == "convolution" and n]
     assert len(convs) >= len(_smoke().conv_channels)
-    named = {prim: [n for _, n in insts if n and n.endswith("/" + prim)]
-             for prim in ("conv_general_dilated", "reduce_window_max",
-                          "select_and_scatter")}
-    assert all(named.values()), named
-    for n in convs + named["conv_general_dilated"]:
+    named = [n for _, n in insts if n and n.endswith("/conv_general_dilated")]
+    assert named
+    for n in convs + named:
         assert scope_of(n) == "conv", n
-    for n in named["reduce_window_max"] + named["select_and_scatter"]:
-        assert scope_of(n) == "pool", n
-    pooled = [n for op, n in insts if op == "select-and-scatter" and n]
+    assert not any(op == "select-and-scatter" for op, _ in insts)
+    index_path = {
+        "fwd": [n for op, n in insts if n and "/jvp(block" in n
+                and (op, n.split("/")[-1]) in (("slice", "slice"),
+                                               ("compare", "gt"))],
+        "bwd": [n for op, n in insts if n and "transpose(jvp(block" in n
+                and (op, n.split("/")[-1]) == ("compare", "eq")]}
+    for way, names in index_path.items():
+        for b in range(cosmoflow.num_pools(_smoke())):
+            assert any(f"jvp(block{b})" in n for n in names), (way, b)
+        for n in names:
+            assert scope_of(n) == "pool", (way, n)
+    pooled = [n for _, n in insts if n and "/pool/" in n]
     assert all(scope_of(n) == "pool" for n in pooled), pooled
-    assert any("transpose(jvp(block0))/pool/" in n
-               for n in named["select_and_scatter"])
     assert any(n and n.split("/")[1] == "optimizer" for _, n in insts)
+
+
+@pytest.mark.parametrize("width,share", [(16, 1.0), (13, 0.0)])
+def test_pool_index_share(width, share):
+    """``pool_index_share`` reads the lowering the step has: 1.0 when
+    every pool takes the saved-index path (even widths, no
+    select-and-scatter), 0 when the one pool of a 13^3 input falls back
+    to ``reduce_window`` and autodiff's select-and-scatter."""
+    cfg = _smoke(width=width)
+    sess = api_compile(RunConfig(model=cfg, global_batch=2))
+    x, y = sess._synthetic_batch()
+    lowered = sess._step_fn.lower(sess.params, sess.opt_state, x, y,
+                                  jnp.asarray(0, jnp.int32)).as_text()
+    assert ("select_and_scatter" in lowered) == (share == 0.0)
+    assert sess.telemetry()["pool_index_share"] == share
+    sess.step((x, y))
+    assert sess.telemetry()["pool_index_share"] == share
+    sess.close()
 
 
 def test_halo_exchange_ops_carry_halo(multidevice):
