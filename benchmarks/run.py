@@ -170,8 +170,8 @@ def bench_fig8_weak_scaling(quick=False):
 # ------------------------------------------------------------ Table I -----
 def bench_table1_memory(quick=False):
     from repro import configs
-    from repro.core.perf_model import memory_per_sample_bytes
-    from repro.launch.specs import conv_net_flops_per_sample
+    from repro.core.perf_model import (conv_net_flops_per_sample,
+                                       memory_per_sample_bytes)
     for w, flops_paper, mem_paper in ((128, 55.55e9, 0.824),
                                       (256, 443.8e9, 6.59),
                                       (512, 3550e9, 52.7)):
@@ -186,8 +186,8 @@ def bench_table1_memory(quick=False):
 # ----------------------------------------------------------- Table II -----
 def bench_table2_conv_peak(quick=False):
     """Distributed conv achieved fraction-of-peak. On this 1-device CPU the
-    halo path degenerates (zero-fill); the sharded peak fractions come from
-    the dry-run roofline (EXPERIMENTS.md). Here: local conv throughput as
+    halo path degenerates (zero-fill); the sharded peak fractions are the
+    chip benchmark's (``benchmarks/chip``). Here: local conv throughput as
     the 'Peak' column analogue + the perf-model Rel prediction."""
     from repro.core.spatial_conv import SpatialPartitioning, conv3d
     from repro import configs
@@ -344,7 +344,6 @@ def bench_fig9_accuracy(quick=False):
 def bench_kernels(quick=False):
     from repro.kernels.conv3d import ops as cops, ref as cref
     from repro.kernels.bn_act import ops as bops, ref as bref
-    from repro.kernels.ssd_scan import ops as sops, ref as sref
     from repro.kernels.halo_pack import ops as hops
 
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 10, 10, 10, 8))
@@ -364,17 +363,6 @@ def bench_kernels(quick=False):
     emit("kernel.bn_act.jnp",
          _timeit(jax.jit(bref.bn_leaky_relu), xb, mean, var, scale, bias),
          "oracle")
-
-    B, L, H, P, N = 1, 64, 2, 8, 16
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    args = (jax.random.normal(ks[0], (B, L, H, P)),
-            jax.nn.softplus(jax.random.normal(ks[1], (B, L, H))),
-            -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5),
-            jax.random.normal(ks[3], (B, L, N)),
-            jax.random.normal(ks[4], (B, L, N)))
-    emit("kernel.ssd_scan.pallas", _timeit(sops.ssd_scan, *args), "chunked")
-    emit("kernel.ssd_scan.jnp", _timeit(jax.jit(sref.ssd_scan), *args),
-         "sequential oracle")
 
     xh = jax.random.normal(jax.random.PRNGKey(7), (2, 16, 8, 8, 4))
     emit("kernel.halo_pack.pallas",

@@ -145,23 +145,15 @@ class RunConfig:
             return self.model
         from repro import configs  # deferred: configs presets import us
 
-        if self.model not in configs.ALL_ARCHS:
+        if self.model not in configs.PAPER_ARCHS:
             close = difflib.get_close_matches(str(self.model),
-                                              configs.ALL_ARCHS, n=3)
+                                              configs.PAPER_ARCHS, n=3)
             hint = (f"did you mean {', '.join(close)}?" if close
-                    else f"choices: {', '.join(configs.ALL_ARCHS)}")
+                    else f"choices: {', '.join(configs.PAPER_ARCHS)}")
             raise RunConfigError("model", f"unknown model {self.model!r}",
                                  hint)
-        cfg = (configs.get_smoke_config(self.model) if self.smoke
-               else configs.get_config(self.model))
-        if not isinstance(cfg, ConvNetConfig):
-            raise RunConfigError(
-                "model",
-                f"{self.model!r} is a {type(cfg).__name__} "
-                f"({cfg.family}), not a conv3d model",
-                "the Session drives the paper's 3D-CNN family; use "
-                "repro.launch.train's GSPMD path for sequence models")
-        return cfg
+        return (configs.get_smoke_config(self.model) if self.smoke
+                else configs.get_config(self.model))
 
     # ------------------------------------------------------ validation ----
     def validate(self, device_count: Optional[int] = None) -> None:

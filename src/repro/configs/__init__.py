@@ -1,88 +1,31 @@
 """Architecture registry: ``get_config(name)`` / ``get_smoke_config(name)``.
 
-Each assigned architecture (plus the paper's own CosmoFlow/3D U-Net) has a
-module ``repro.configs.<id>`` exporting ``CONFIG`` (exact published spec,
-source cited) and ``SMOKE`` (reduced same-family variant: <=2 layers,
-d_model <= 512, <=4 experts — used by the CPU smoke tests).
-
-``PLANS`` records the parallelism plan per (arch, input shape):
-``tp`` tensor parallel, ``cp`` context/sequence parallel (the paper's
-spatial partitioning on the sequence axis), ``ep`` expert parallel (+cp
-attention). Conv nets use shard_map spatial partitioning directly.
+The paper's own models: CosmoFlow at three input widths and the 3D
+U-Net. Each has a module ``repro.configs.<id>`` exporting the published
+spec (source cited) and ``SMOKE``, a reduced same-family variant used by
+the CPU tests.
 """
 from __future__ import annotations
 
 import importlib
-from typing import Dict, Tuple
 
-from repro.configs.base import (
-    INPUT_SHAPES,
-    ConvNetConfig,
-    HybridConfig,
-    InputShape,
-    SSMConfig,
-    TransformerConfig,
-)
+from repro.configs.base import ConvNetConfig
 
 _MODULES = {
-    "hubert-xlarge": "hubert_xlarge",
-    "zamba2-1.2b": "zamba2_1p2b",
-    "phi3.5-moe": "phi35_moe",
-    "gemma2-2b": "gemma2_2b",
-    "arctic-480b": "arctic_480b",
-    "phi3-mini": "phi3_mini",
-    "phi3-vision": "phi3_vision",
-    "llama3-405b": "llama3_405b",
-    "qwen1.5-0.5b": "qwen15_0p5b",
-    "mamba2-370m": "mamba2_370m",
     "cosmoflow-128": "cosmoflow",
     "cosmoflow-256": "cosmoflow",
     "cosmoflow-512": "cosmoflow",
     "unet3d-256": "unet3d",
 }
 
-ASSIGNED = [
-    "hubert-xlarge", "zamba2-1.2b", "phi3.5-moe", "gemma2-2b",
-    "arctic-480b", "phi3-mini", "phi3-vision", "llama3-405b",
-    "qwen1.5-0.5b", "mamba2-370m",
-]
-PAPER_ARCHS = ["cosmoflow-128", "cosmoflow-256", "cosmoflow-512",
-               "unet3d-256"]
-ALL_ARCHS = ASSIGNED + PAPER_ARCHS
-
-# parallelism plan per (arch, shape); conv nets are handled by shard_map.
-_DEFAULT_PLAN = {"train_4k": "tp", "prefill_32k": "cp",
-                 "decode_32k": "cp", "long_500k": "cp"}
-PLANS: Dict[str, Dict[str, str]] = {
-    "hubert-xlarge": {"train_4k": "tp", "prefill_32k": "cp"},
-    "zamba2-1.2b": {"train_4k": "tp", "prefill_32k": "cp",
-                    "decode_32k": "cp", "long_500k": "cp"},
-    "phi3.5-moe": {"train_4k": "ep", "prefill_32k": "ep",
-                   "decode_32k": "ep"},
-    "gemma2-2b": dict(_DEFAULT_PLAN, train_4k="cp"),
-    "arctic-480b": {"train_4k": "ep", "prefill_32k": "ep",
-                    "decode_32k": "ep"},
-    "phi3-mini": {"train_4k": "tp", "prefill_32k": "tp",
-                  "decode_32k": "cp"},
-    "phi3-vision": {"train_4k": "tp", "prefill_32k": "tp",
-                    "decode_32k": "cp"},
-    "llama3-405b": {"train_4k": "tp", "prefill_32k": "tp",
-                    "decode_32k": "cp"},
-    "qwen1.5-0.5b": {"train_4k": "tp", "prefill_32k": "tp",
-                     "decode_32k": "cp"},
-    "mamba2-370m": {"train_4k": "tp", "prefill_32k": "cp",
-                    "decode_32k": "cp", "long_500k": "cp"},
-}
-
-# archs where params are additionally FSDP-sharded over the data axes
-FSDP_ARCHS = {"llama3-405b", "arctic-480b", "phi3.5-moe"}
+PAPER_ARCHS = list(_MODULES)
 
 
 def _module(name: str):
     return importlib.import_module(f"repro.configs.{_MODULES[name]}")
 
 
-def get_config(name: str):
+def get_config(name: str) -> ConvNetConfig:
     mod = _module(name)
     if name.startswith("cosmoflow-"):
         width = int(name.split("-")[1])
@@ -90,36 +33,5 @@ def get_config(name: str):
     return mod.CONFIG
 
 
-def get_smoke_config(name: str):
-    mod = _module(name)
-    return mod.SMOKE
-
-
-def plan_for(arch: str, shape: str) -> str:
-    return PLANS.get(arch, {}).get(shape, "tp")
-
-
-def applicable_shapes(arch: str) -> Tuple[str, ...]:
-    """Which of the four input shapes apply (assignment-mandated skips)."""
-    cfg = get_config(arch)
-    if isinstance(cfg, ConvNetConfig):
-        return ("train_4k",)  # conv nets: training only (paper scope)
-    shapes = ["train_4k", "prefill_32k"]
-    if getattr(cfg, "supports_decode", True):
-        shapes.append("decode_32k")
-        if getattr(cfg, "subquadratic", False):
-            shapes.append("long_500k")
-    return tuple(shapes)
-
-
-def skip_reason(arch: str, shape: str) -> str:
-    cfg = get_config(arch)
-    if isinstance(cfg, ConvNetConfig):
-        return ("conv net (paper model): token shapes N/A; evaluated on its "
-                "own 3-D volumes")
-    if shape in ("decode_32k", "long_500k") and not cfg.supports_decode:
-        return "encoder-only: no decode step (DESIGN.md §7)"
-    if shape == "long_500k" and not cfg.subquadratic:
-        return ("pure full attention: long_500k requires sub-quadratic "
-                "attention (DESIGN.md §7)")
-    return ""
+def get_smoke_config(name: str) -> ConvNetConfig:
+    return _module(name).SMOKE

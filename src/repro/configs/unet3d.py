@@ -19,8 +19,8 @@ SMOKE = ConvNetConfig(
 
 def run_preset(full: bool = False):
     """Canonical ``RunConfig`` for the U-Net e2e example: the smoke
-    variant by default (the 256^3 config is dry-run scale on CPU), LR
-    1e-3 linearly decayed over 30 steps."""
+    variant by default (the 256^3 config is chip scale, too large for
+    the CPU), LR 1e-3 linearly decayed over 30 steps."""
     from repro.api.config import RunConfig  # deferred: api imports configs
 
     return RunConfig(model=CONFIG if full else SMOKE, global_batch=2,

@@ -1,8 +1,8 @@
 """Thin wrappers over the jax surface the repo uses (jax 0.9).
 
 Each helper pins the one argument convention the codebase relies on, so
-call sites stay short: ``shard_map`` with the replication check off, a
-``dict`` from ``cost_analysis``, and meshes with explicit ``Auto`` axes.
+call sites stay short: ``shard_map`` with the replication check off, the
+static size of a named axis, and meshes with explicit ``Auto`` axes.
 """
 from __future__ import annotations
 
@@ -17,17 +17,6 @@ def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
     activations, which the static checker rejects."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=check)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict (empty when the backend
-    reports none)."""
-    return compiled.cost_analysis() or {}
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` context."""
-    return jax.set_mesh(mesh)
 
 
 def axis_size(axis_name) -> int:

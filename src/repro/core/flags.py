@@ -1,15 +1,10 @@
-"""Process-wide lowering flags.
+"""Process-wide lowering flags, each read by the conv-net path.
 
-* ``scan_unroll``: fully unroll the over-layers ``lax.scan``. The dry-run
-  enables this because XLA's ``cost_analysis`` counts a while-loop body
-  ONCE (not x trip-count), which would silently under-report FLOPs/bytes in
-  the roofline. Runtime training keeps the rolled loop (smaller programs).
-* ``remat``: wrap each layer body in ``jax.checkpoint`` (recompute
-  activations in backward) — the standard memory/compute trade; without it
-  the 4k-train shapes hold every layer's activations live. Sequence
-  models apply it through ``maybe_remat``; conv nets honor it per block
-  whenever their ``ParallelPlan`` sets no stage-level ``remat`` of its
-  own (a plan that does set one wins outright — DESIGN.md §9).
+* ``remat``: wrap each conv block in ``jax.checkpoint`` (recompute
+  activations in backward) — the standard memory/compute trade. Conv
+  nets honor it per block whenever their ``ParallelPlan`` sets no
+  stage-level ``remat`` of its own (a plan that does set one wins
+  outright — DESIGN.md §9).
 * ``overlap_halo``: lower distributed convs via the interior/boundary
   decomposition with packed halo exchange (DESIGN.md §3) instead of the
   blocking exchange-concat-conv. On by default; the blocking path remains
@@ -31,10 +26,8 @@ from __future__ import annotations
 
 import contextlib
 
-_STATE = {"scan_unroll": False, "remat": False,
-          "ep_alltoall": True, "seq_shard_acts": False,
-          "tp_shardmap_attn": False, "overlap_halo": True,
-          "grad_comm": "overlap", "pipeline_link_latency_s": 0.0}
+_STATE = {"remat": False, "overlap_halo": True, "grad_comm": "overlap",
+          "pipeline_link_latency_s": 0.0}
 
 
 def get(name: str):
@@ -61,14 +54,3 @@ def flags(**kw):
         yield
     finally:
         _STATE.update(old)
-
-
-def scan_kwargs(length: int) -> dict:
-    return {"unroll": length} if _STATE["scan_unroll"] else {}
-
-
-def maybe_remat(fn):
-    return jax.checkpoint(fn) if _STATE["remat"] else fn
-
-
-import jax  # noqa: E402  (bottom import keeps module import cheap)

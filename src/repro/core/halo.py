@@ -245,23 +245,9 @@ def conv_halo_widths(kernel: int, stride: int) -> Tuple[int, int]:
     return lo, total - lo
 
 
-def exchange_carry_right(
-    carry: jax.Array, axis_name: str
-) -> jax.Array:
-    """Pass a per-shard carry to the *next* rank (rank 0 receives zeros).
-
-    Used by the sequence-parallel SSD scan: the SSM state at the end of
-    shard ``i`` is the initial state of shard ``i+1`` — a 1-element halo.
-    """
-    n = compat.axis_size(axis_name)
-    if n == 1:
-        return jnp.zeros_like(carry)
-    return _send(carry, axis_name, _shift_perm(n, +1))
-
-
 def all_gather_dim(x: jax.Array, axis_name: str, dim: int) -> jax.Array:
     """All-gather shards along ``dim`` (the degenerate 'halo = whole domain'
-    case, used for full attention over a sequence-sharded KV)."""
+    case: the replicated reshard and the spatial all-gather)."""
     if compat.axis_size(axis_name) == 1:
         return x
     return lax.all_gather(x, axis_name, axis=dim, tiled=True)

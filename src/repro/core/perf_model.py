@@ -139,6 +139,39 @@ def unet_layers(cfg: ConvNetConfig) -> List[ConvLayer]:
     return layers
 
 
+def conv_net_flops_per_sample(cfg: ConvNetConfig, forward_only=False) -> float:
+    """Analytic conv FLOPs/sample (must reproduce paper Table I)."""
+    k3 = cfg.kernel_size ** 3
+    total = 0.0
+    if cfg.arch == "cosmoflow":
+        w, cin = cfg.input_width, cfg.in_channels
+        npool = min(int(math.log2(w)) - 2, len(cfg.conv_channels))
+        for i, c in enumerate(cfg.conv_channels):
+            ow = w // 2 if i == 3 else w
+            total += 2 * k3 * cin * c * ow ** 3
+            w = ow // 2 if i < npool else ow
+            cin = c
+    else:
+        w, cin, ch = cfg.input_width, cfg.in_channels, cfg.base_channels
+        enc = []
+        for _ in range(cfg.depth):
+            total += 2 * k3 * cin * ch * w ** 3
+            total += 2 * k3 * ch * 2 * ch * w ** 3
+            enc.append(2 * ch)
+            cin, ch, w = 2 * ch, 2 * ch, w // 2
+        total += 2 * k3 * cin * ch * w ** 3
+        total += 2 * k3 * ch * 2 * ch * w ** 3
+        up_in = 2 * ch
+        for skip in reversed(enc):
+            w *= 2
+            total += 2 * 8 * up_in * skip * w ** 3  # deconv
+            total += 2 * k3 * 2 * skip * skip * w ** 3
+            total += 2 * k3 * skip * skip * w ** 3
+            up_in = skip
+        total += 2 * up_in * cfg.out_dim * w ** 3
+    return total if forward_only else 3.0 * total  # fwd + bwd-data + bwd-filter
+
+
 def _layer_fp_time(hw: Hardware, l: ConvLayer, ways: int,
                    per_gpu_batch: float,
                    overlap: bool = True,

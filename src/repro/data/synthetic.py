@@ -105,24 +105,3 @@ def make_segmentation_dataset(
         cubes.append(np.stack(chans, axis=-1))
         labels.append(lab)
     return cubes, labels
-
-
-def make_token_dataset(
-    num_tokens: int, vocab: int, seed: int = 0, order: int = 2,
-) -> np.ndarray:
-    """Synthetic LM corpus: a sparse Markov chain so that models can reach
-    non-trivial loss (< log V) within a few hundred steps."""
-    rng = np.random.default_rng(seed)
-    # each (prev % 64) state prefers a small set of successors
-    n_states = 64
-    succ = rng.integers(0, vocab, size=(n_states, 8))
-    toks = np.empty(num_tokens, np.int32)
-    toks[0] = rng.integers(vocab)
-    r = rng.random(num_tokens)
-    choice = rng.integers(0, 8, size=num_tokens)
-    for t in range(1, num_tokens):
-        if r[t] < 0.8:
-            toks[t] = succ[toks[t - 1] % n_states, choice[t]]
-        else:
-            toks[t] = rng.integers(vocab)
-    return toks

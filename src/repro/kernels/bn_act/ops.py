@@ -3,8 +3,8 @@
 Two properties the model hot path (``core/dist_norm.py``) relies on:
 
 * the interpret-mode decision is made at TRACE time, not import time — a
-  backend selected after import (tests forcing host platforms, dryruns
-  targeting TPU) must win;
+  backend selected after import (tests forcing host platforms, AOT
+  compiles for a described TPU) must win;
 * the kernel carries a ``custom_vjp`` whose backward is the jnp oracle's
   VJP, so the fused forward can sit under ``value_and_grad`` (Pallas
   calls have no transpose rule of their own).

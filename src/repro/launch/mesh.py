@@ -1,12 +1,11 @@
-"""Production mesh construction.
+"""Mesh construction over the visible devices.
 
 Defined as FUNCTIONS (not module constants) so importing this module never
-touches jax device state. The dry-run launcher sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; tests and benches see the real (1-device) CPU.
+touches jax device state: tests force host device counts before building
+one.
 
-Spatial axes: both builders accept ``spatial=((name, size), ...)`` so a
-``ParallelPlan`` (DESIGN.md §5) referencing named spatial axes can be
+Spatial axes: ``make_local_mesh`` accepts ``spatial=((name, size), ...)``
+so a ``ParallelPlan`` (DESIGN.md §5) referencing named spatial axes can be
 instantiated without ad-hoc ``compat.make_mesh`` calls; ``make_plan_mesh``
 builds the mesh straight from a plan's recorded axis degrees.
 """
@@ -19,30 +18,6 @@ import jax
 from repro.core import compat
 
 SpatialAxes = Sequence[Tuple[str, int]]
-
-
-def make_production_mesh(*, multi_pod: bool = False,
-                         spatial: SpatialAxes = ()):
-    """The 256-chip pod mesh (x2 pods with ``multi_pod``). By default the
-    model/spatial side is the single 16-way ``model`` axis; ``spatial``
-    replaces it with named spatial axes (e.g. ``(("d", 8), ("h", 2))``),
-    keeping the per-pod chip count at 256 by sizing ``data`` to the
-    remainder."""
-    chips = 256
-    if spatial:
-        n_spatial = 1
-        for _, s in spatial:
-            n_spatial *= s
-        if chips % n_spatial:
-            raise ValueError(
-                f"spatial degrees {spatial} do not divide {chips}")
-        shape = (chips // n_spatial,) + tuple(s for _, s in spatial)
-        axes = ("data",) + tuple(a for a, _ in spatial)
-    else:
-        shape, axes = (16, 16), ("data", "model")
-    if multi_pod:
-        shape, axes = (2,) + shape, ("pod",) + axes
-    return compat.make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, data: int = 1, *,
@@ -93,9 +68,3 @@ def make_pipeline_meshes(plan) -> Tuple[jax.sharding.Mesh, ...]:
         jax.sharding.Mesh(
             np.asarray(devices[g * d:(g + 1) * d]).reshape(shape), axes)
         for g in range(n_groups))
-
-
-# TPU v5e hardware constants for the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12     # FLOP/s
-HBM_BW = 819e9               # B/s
-ICI_BW_PER_LINK = 50e9       # B/s per link

@@ -3,9 +3,7 @@
 One serving entry point: ``InferenceSession`` (forward-only sessions
 compiled from ``RunConfig(mode="infer")`` or restored from training
 checkpoints) and ``ServingHarness`` (the batched request queue its
-``.serve()`` starts). The LM prefill/decode side door lives in
-``repro.serve.lm``; the old ``repro.serve.serve`` import location is a
-deprecation shim over it.
+``.serve()`` starts).
 """
 from repro.serve.harness import ServingHarness
 from repro.serve.session import InferenceSession, InferReport, compile_infer

@@ -1,19 +1,16 @@
 """Train-step builders.
 
-Two distribution styles, matching DESIGN.md:
+The paper's conv nets train through one distribution style: a
+whole-model ``jax.shard_map`` with explicit halo collectives. Gradient
+reduction follows the ``grad_comm`` mode (DESIGN.md §4): per-layer
+bucketed reduction hooks that fire during backward (``overlap``,
+default — the data-parallel allreduce of paper Fig. 2 fused with the
+spatial-partition reduction and overlapped with backprop), the seed's
+tail tree-wide psum (``monolithic``, equivalence oracle), or ZeRO-1
+``psum_scatter`` + sharded optimizer + ``all_gather``
+(``reduce_scatter``).
 
-* Conv nets (the paper's models): whole-model ``jax.shard_map`` with
-  explicit halo collectives. Gradient reduction follows the ``grad_comm``
-  mode (DESIGN.md §4): per-layer bucketed reduction hooks that fire
-  during backward (``overlap``, default — the data-parallel allreduce of
-  paper Fig. 2 fused with the spatial-partition reduction and overlapped
-  with backprop), the seed's tail tree-wide psum (``monolithic``,
-  equivalence oracle), or ZeRO-1 ``psum_scatter`` + sharded optimizer +
-  ``all_gather`` (``reduce_scatter``).
-* Sequence models: GSPMD ``jax.jit`` with sharding constraints from the
-  ShardingPolicy; XLA inserts the collectives.
-
-This is the INTERNAL assembly layer. Drivers (examples, launchers,
+This is the INTERNAL assembly layer. Drivers (examples, the CLI,
 bench e2e paths) go through ``repro.api.compile`` (DESIGN.md §10),
 which owns the mesh/plan/precision/opt-state threading and lowers to
 the builders here; calling ``make_convnet_train_step`` directly from a
@@ -26,7 +23,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent import futures as _futures
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +36,6 @@ from repro.core import grad_comm as grad_comm_lib
 from repro.core import plan as plan_lib
 from repro.core import precision as precision_lib
 from repro.core import reshard as reshard_lib
-from repro.core.sharding import ShardingPolicy
 from repro.core.spatial_conv import SpatialPartitioning
 from repro.models import cosmoflow as cosmoflow_lib
 from repro.models import unet3d as unet_lib
@@ -1200,42 +1196,3 @@ def make_pipeline_train_step(
         return out_params, tuple(new_opt), total
 
     return step
-
-
-# ------------------------------------------------------ sequence models ---
-def make_lm_train_step(
-    loss_fn: Callable,  # (params, batch, cfg, policy, mesh) -> scalar
-    cfg,
-    mesh,
-    policy: ShardingPolicy,
-    optimizer,
-    *,
-    batch_specs: Dict[str, P],
-    param_specs: Any,  # pytree of P matching params
-    jit: bool = True,
-):
-    """GSPMD train step for transformer/SSM/hybrid models."""
-
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(
-            params, batch, cfg, policy, mesh)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
-        return new_params, new_opt, loss
-
-    if not jit or mesh is None:
-        return step
-
-    def nshard(spec_tree, tree):
-        return jax.tree.map(
-            lambda s: NamedSharding(mesh, s), spec_tree,
-            is_leaf=lambda x: isinstance(x, P))
-
-    p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), param_specs,
-                        is_leaf=lambda x: isinstance(x, P))
-    opt_sh = None  # inferred: optimizer state mirrors params
-    b_sh = {k: NamedSharding(mesh, v) for k, v in batch_specs.items()}
-    return jax.jit(
-        step,
-        in_shardings=(p_sh, None, b_sh),
-        donate_argnums=(0, 1),
-    )

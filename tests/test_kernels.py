@@ -123,24 +123,3 @@ def test_bn_act_kernel(shape, c, slope):
                              negative_slope=slope)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("L,H,P,N,chunk", [
-    (32, 2, 8, 16, 8), (64, 3, 8, 16, 16), (64, 1, 16, 8, 64),
-    (48, 2, 4, 4, 12),
-])
-def test_ssd_scan_kernel(L, H, P, N, chunk):
-    from repro.kernels.ssd_scan import ops, ref
-    B = 2
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    x = jax.random.normal(ks[0], (B, L, H, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, L, H)))
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5)
-    Bm = jax.random.normal(ks[3], (B, L, N))
-    Cm = jax.random.normal(ks[4], (B, L, N))
-    y, s = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
-    y_ref, s_ref = ref.ssd_scan(x, dt, A, Bm, Cm)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               rtol=3e-4, atol=3e-4)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
-                               rtol=3e-4, atol=3e-4)

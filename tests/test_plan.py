@@ -467,10 +467,6 @@ print("OK")
 
 
 def test_mesh_spatial_axes(multidevice):
-    from repro.launch import mesh as mesh_lib
-
-    with pytest.raises(ValueError, match="divide"):
-        mesh_lib.make_production_mesh(spatial=(("d", 3),))
     multidevice("""
 from repro.core import compat
 from repro import configs
@@ -487,24 +483,6 @@ pm = make_plan_mesh(pl)
 assert pm.shape == {'data': 2, 'd': 2}, pm.shape
 print("OK")
 """, devices=4)
-
-
-def test_conv_batch_specs_follow_plan():
-    from jax.sharding import PartitionSpec as P
-
-    from repro.core import compat
-    from repro.launch import specs
-
-    cfg = configs.get_smoke_config("cosmoflow-512")
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    pl = plan_lib.uniform_plan(cfg, data_degrees=(1,))
-    b = specs.conv_batch_specs(cfg, pl, mesh, global_batch=4)
-    assert b["x"].sharding.spec == P("data", "model", None, None, None)
-    assert b["y"].sharding.spec == P("data", None)
-    ucfg = configs.get_smoke_config("unet3d-256")
-    bu = specs.conv_batch_specs(ucfg, plan_lib.uniform_plan(ucfg), mesh,
-                                global_batch=4)
-    assert bu["y"].sharding.spec == P("data", "model", None, None)
 
 
 def test_bench_provenance_fields():

@@ -9,8 +9,6 @@ hypothesis = pytest.importorskip(
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core.halo import conv_halo_widths
-from repro.models.layers import chunked_attention
-from repro.models.mamba2 import ssd_chunked
 
 
 @given(k=st.integers(1, 7), s=st.integers(1, 4))
@@ -19,83 +17,6 @@ def test_halo_widths_cover_same_padding(k, s):
     lo, hi = conv_halo_widths(k, s)
     assert lo + hi == max(k - s, 0)
     assert 0 <= lo <= hi <= lo + 1
-
-
-@settings(deadline=None, max_examples=20)
-@given(
-    s=st.sampled_from([8, 16, 24]),
-    h=st.sampled_from([1, 2, 4]),
-    g=st.sampled_from([1, 2]),
-    window=st.sampled_from([0, 3, 9]),
-    causal=st.booleans(),
-    chunk=st.sampled_from([4, 8, 64]),
-    seed=st.integers(0, 2 ** 16),
-)
-def test_chunked_attention_matches_plain_softmax(s, h, g, window, causal,
-                                                 chunk, seed):
-    """Online-softmax chunked attention == plain masked softmax, for any
-    chunking, GQA grouping, window and causality."""
-    if not causal and window:
-        window = 0
-    hd, B = 8, 2
-    H, Hkv = h * g, h
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (B, s, H, hd))
-    k = jax.random.normal(ks[1], (B, s, Hkv, hd))
-    v = jax.random.normal(ks[2], (B, s, Hkv, hd))
-    pos = jnp.arange(s)
-    got = chunked_attention(q, k, v, q_pos=pos, kv_pos=pos, causal=causal,
-                            window=window, kv_chunk=chunk)
-    kk = jnp.repeat(k, g, axis=2)
-    vv = jnp.repeat(v, g, axis=2)
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * hd ** -0.5
-    mask = jnp.ones((s, s), bool)
-    if causal:
-        mask &= pos[None, :] <= pos[:, None]
-    if window:
-        mask &= pos[:, None] - pos[None, :] < window
-    sc = jnp.where(mask, sc, -jnp.inf)
-    ref = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, axis=-1), vv)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=3e-5, atol=3e-5)
-
-
-@settings(deadline=None, max_examples=15)
-@given(
-    l=st.sampled_from([16, 32, 64]),
-    chunk=st.sampled_from([4, 8, 16]),
-    split=st.sampled_from([2, 4]),
-    seed=st.integers(0, 2 ** 16),
-)
-def test_ssd_chunk_invariance_and_shard_composition(l, chunk, split, seed):
-    """SSD output must be invariant to the chunk size, and splitting the
-    sequence into shards + carrying the state must compose exactly
-    (the core invariant behind the paper-style sequence partitioning)."""
-    B, H, P, N = 1, 2, 4, 8
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    x = jax.random.normal(ks[0], (B, l, H, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, l, H)))
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, l, N))
-    Cm = jax.random.normal(ks[4], (B, l, N))
-    y_base, ex_base = ssd_chunked(x, dt, A, Bm, Cm, chunk=l)
-    y_c, _ = ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
-    np.testing.assert_allclose(np.asarray(y_c), np.asarray(y_base),
-                               rtol=2e-4, atol=2e-4)
-    # shard composition
-    w = l // split
-    ys, state = [], None
-    for i in range(split):
-        sl = slice(i * w, (i + 1) * w)
-        y_i, ex = ssd_chunked(x[:, sl], dt[:, sl], A, Bm[:, sl], Cm[:, sl],
-                              chunk=min(chunk, w), init_state=state)
-        state = ex.final_state
-        ys.append(y_i)
-    np.testing.assert_allclose(np.asarray(jnp.concatenate(ys, 1)),
-                               np.asarray(y_base), rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(state),
-                               np.asarray(ex_base.final_state),
-                               rtol=2e-4, atol=2e-4)
 
 
 @settings(deadline=None, max_examples=20)

@@ -16,7 +16,6 @@ Three pillars:
 import os
 import threading
 import time
-import warnings
 
 import numpy as np
 import pytest
@@ -395,19 +394,6 @@ def test_telemetry_serve_keys_route_through_registry():
         snap = {g: sess._metrics.gauges()[g].value
                 for g in ("serve.requests", "serve.batches")}
         assert snap["serve.requests"] == 2.0
-
-
-# -------------------------------------------------------------- LM shim ----
-def test_lm_serve_shim_deprecated():
-    import importlib
-    import repro.serve.serve as shim  # noqa: F401 - import fires the warning
-
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        importlib.reload(shim)
-    assert any(issubclass(x.category, DeprecationWarning) for x in w)
-    from repro.serve.lm import generate, make_serve_fns  # noqa: F401
-    assert shim.generate is generate
 
 
 # ------------------------------------------------------- multidevice ----

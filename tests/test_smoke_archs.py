@@ -1,84 +1,17 @@
-"""Per-architecture SMOKE tests (assignment deliverable f).
+"""Per-architecture SMOKE tests.
 
-Each assigned architecture's REDUCED variant (<=2 layers, d_model <= 512,
-<=4 experts, same family) runs one forward/train step on the 1-device CPU,
-asserting output shapes and no NaNs. Decode-capable archs additionally run
-one serve_step against a small cache.
+Each paper model's REDUCED variant (same family, input width <= 32) runs
+one train step on a trivial 1x1 mesh on the 1-device CPU, asserting a
+finite loss and finite updated params.
 """
 import jax
 import jax.numpy as jnp
-
-from repro.core import compat
 import numpy as np
 import pytest
 
 from repro import configs
-from repro.configs.base import (
-    ConvNetConfig, HybridConfig, SSMConfig, TransformerConfig,
-)
-from repro.models import ssm_lm, transformer
+from repro.core import compat
 from repro.optim.adam import Adam, constant
-
-B, S = 2, 32
-
-
-def _batch_for(cfg, key):
-    k1, k2, k3 = jax.random.split(key, 3)
-    if getattr(cfg, "family", "") == "audio":
-        return {"tokens": jax.random.normal(k1, (B, S, cfg.d_model)) * 0.1,
-                "labels": jax.random.randint(k2, (B, S), 0, cfg.vocab_size)}
-    if getattr(cfg, "family", "") == "vlm":
-        img = jax.random.normal(k3, (B, 8, cfg.d_model)) * 0.02
-        return {"tokens": jax.random.randint(k1, (B, S), 0, cfg.vocab_size),
-                "labels": jax.random.randint(k2, (B, S), 0, cfg.vocab_size),
-                "image_embeds": img}
-    return {"tokens": jax.random.randint(k1, (B, S), 0, cfg.vocab_size),
-            "labels": jax.random.randint(k2, (B, S), 0, cfg.vocab_size)}
-
-
-@pytest.mark.parametrize("arch", configs.ASSIGNED)
-def test_smoke_train_step(arch):
-    cfg = configs.get_smoke_config(arch)
-    assert cfg.num_layers <= 2 or isinstance(cfg, (SSMConfig, HybridConfig))
-    assert cfg.d_model <= 512
-    if isinstance(cfg, TransformerConfig) and cfg.num_experts:
-        assert cfg.num_experts <= 4
-    key = jax.random.PRNGKey(0)
-    is_ssm = isinstance(cfg, (SSMConfig, HybridConfig))
-    mod = ssm_lm if is_ssm else transformer
-    params = mod.init_params(key, cfg)
-    batch = _batch_for(cfg, jax.random.PRNGKey(1))
-    loss_fn = mod.lm_loss
-    opt = Adam(lr=constant(1e-3))
-    opt_state = opt.init(params)
-
-    @jax.jit
-    def step(p, o, b):
-        loss, grads = jax.value_and_grad(loss_fn)(p, b, cfg)
-        np_, no = opt.update(grads, o, p)
-        return np_, no, loss
-
-    params, opt_state, loss = step(params, opt_state, batch)
-    assert np.isfinite(float(loss)), arch
-    for leaf in jax.tree.leaves(params):
-        assert np.all(np.isfinite(np.asarray(leaf))), arch
-
-
-@pytest.mark.parametrize("arch", [a for a in configs.ASSIGNED
-                                  if configs.get_config(a).supports_decode])
-def test_smoke_decode_step(arch):
-    cfg = configs.get_smoke_config(arch)
-    is_ssm = isinstance(cfg, (SSMConfig, HybridConfig))
-    mod = ssm_lm if is_ssm else transformer
-    params = mod.init_params(jax.random.PRNGKey(0), cfg)
-    cache = mod.init_cache(cfg, B, 16)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (B, 1), 0,
-                              cfg.vocab_size)
-    logits, cache = jax.jit(
-        lambda p, c, t: mod.decode_step(p, c, t, cfg))(params, cache, toks)
-    assert logits.shape == (B, cfg.vocab_size), arch
-    assert np.all(np.isfinite(np.asarray(logits))), arch
-    assert int(cache["pos"]) == 1
 
 
 @pytest.mark.parametrize("arch", ["cosmoflow-512", "unet3d-256"])
